@@ -52,8 +52,8 @@ A partial run (``--workload formats`` etc.) merges its rows into an
 existing ``BENCH_parser.json`` instead of clobbering the other workloads'
 entries; the existing file's ``meta`` is kept (full-run provenance).
 
-All wall-clock on the CPU backend (this container's "device"); the TPU-
-projected numbers live in EXPERIMENTS.md §Roofline from the dry-run.
+Wall-clock is on whatever platform JAX runs on, and the report's ``meta``
+names it; a CPU run times the Pallas interpreter, not a device.
 
 ``BENCH_parser.json`` schema (one object per run)::
 
@@ -83,17 +83,13 @@ projected numbers live in EXPERIMENTS.md §Roofline from the dry-run.
               "partition_impl": str,      # resolved (never "auto")
               "fuse_typeconv": bool,
               "typeconv_path": str,       # reference | unfused |
-                                          #   fused-windowed | fused-wholecss
+                                          #   fused-windowed
               "execute_path": str         # staged | fused — the resolved
             }                             #   whole-pipeline tier
           },
           "fused_vs_unfused": {           # pallas/fused vs pallas/unfused,
             "speedup": float,             # materialize_us ratio (unfused/
             "no_slower": bool             # fused); the PR-3 fusion metric
-          },
-          "windowed_vs_wholecss": {       # pallas/fused vs pallas/
-            "speedup": float,             # fused-wholecss, same ratio; the
-            "no_slower": bool             # window-DMA accountability metric
           },
           "fused_vs_staged": {            # pallas/fused-pipeline vs pallas/
             "speedup": float,             # fused, us_per_call ratio (staged/
@@ -226,11 +222,7 @@ projected numbers live in EXPERIMENTS.md §Roofline from the dry-run.
       }
     }
 
-``no_slower`` allows a 5% timing-noise margin.  On this interpret-mode
-container the windowed-vs-wholecss ratio measures plan+cond overhead only —
-the VMEM-capacity win the windows buy exists only on real hardware, where
-the whole-CSS variant stops fitting at ~16 MB/core and this ratio becomes
-the difference between parsing and not parsing.
+``no_slower`` allows a 5% timing-noise margin.
 
 Known tuned-config regression note (interpret CPU): past BENCH runs show
 the whole-pipeline megakernel *regressing* the clf / jsonl / zone formats
@@ -255,6 +247,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import dataset, emit, gbps, taxi_parser, time_fn, yelp_parser
+from repro import compile_cache
+from repro.core.backends import interpret_kernels
 from repro.core.streaming import StreamingParser
 from repro.tune import measure as tune_measure
 
@@ -267,9 +261,7 @@ N_TAXI = 8000    # ~0.7 MB
 #: *windowed* fused gather+convert kernels — what every driver runs);
 #: ``pallas/fused-pipeline`` is the whole-pipeline megakernel
 #: (``fuse_pipeline=True`` — one kernel per partition, no HBM round-trips
-#: between replay and typed columns); ``pallas/fused-wholecss`` pins the
-#: pre-window fused kernels (whole CSS in VMEM — the windowed path's
-#: baseline, and on real hardware the VMEM-capped variant);
+#: between replay and typed columns; interpreter only);
 #: ``pallas/unfused`` is the pre-fusion pallas path (jnp scatter partition
 #: + XLA-gather typeconv) the fusion must not regress against; the rest
 #: sweep the partition impls, the radix *kernel* included (on this
@@ -281,7 +273,6 @@ VARIANTS = {
     "reference/scatter2": ("reference", "scatter2", True, 0, False),
     "pallas/fused": ("pallas", "auto", True, 0, False),
     "pallas/fused-pipeline": ("pallas", "auto", True, 0, True),
-    "pallas/fused-wholecss": ("pallas", "auto", True, -1, False),
     "pallas/unfused": ("pallas", "scatter", False, 0, False),
     "pallas/kernel+fused": ("pallas", "kernel", True, 0, False),
     "pallas/scatter+fused": ("pallas", "scatter", True, 0, False),
@@ -401,6 +392,8 @@ def materialize_sweep(n_records=250, backends=("reference", "pallas"),
         for label, (backend, impl, fuse, window_rows, fuse_pipe) in VARIANTS.items():
             if backend not in backends:
                 continue
+            if fuse_pipe and not interpret_kernels():
+                continue
             p = mk(max_records=1 << 12, backend=backend, partition_impl=impl,
                    fuse_typeconv=fuse, window_rows=window_rows,
                    fuse_pipeline=fuse_pipe)
@@ -487,20 +480,6 @@ def materialize_sweep(n_records=250, backends=("reference", "pallas"),
                 "no_slower": bool(tf <= tu * 1.05),  # 5% timing-noise margin
             }
             emit(f"materialize/{kind}/fused_speedup", 0.0, f"{tu / tf:.3f}x")
-        # The window-DMA accountability metric: the windowed default vs the
-        # pre-window whole-CSS-in-VMEM fused kernels.  On interpret-mode CPU
-        # this prices the plan+cond overhead; on real hardware the wholecss
-        # variant caps per-parse CSS at VMEM capacity and the windowed path
-        # is what keeps scaling.
-        wholecss = "pallas/fused-wholecss"
-        if fused in entry["variants"] and wholecss in entry["variants"]:
-            tf = entry["variants"][fused]["materialize_us"]
-            tw = entry["variants"][wholecss]["materialize_us"]
-            entry["windowed_vs_wholecss"] = {
-                "speedup": tw / tf,
-                "no_slower": bool(tf <= tw * 1.05),  # 5% timing-noise margin
-            }
-            emit(f"materialize/{kind}/windowed_vs_wholecss", 0.0, f"{tw / tf:.3f}x")
         # The whole-pipeline-fusion accountability metric: the megakernel
         # vs the staged backend default, end-to-end (the megakernel has no
         # separable materialize stage).  On interpret-mode CPU this is a
@@ -533,7 +512,8 @@ def _base_report(n_records: int) -> dict:
     — the same identity the autotuner cache keys on (``tune.cache``), so a
     bench row and the tuned config it exercised name the same machine."""
     dev = jax.devices()[0]
-    return {"meta": {"interpret": True, "n_records_base": n_records,
+    return {"meta": {"interpret": interpret_kernels(),
+                     "n_records_base": n_records,
                      "device_kind": str(dev.device_kind),
                      "platform": str(dev.platform),
                      "jax_version": jax.__version__,
@@ -582,7 +562,9 @@ def formats_sweep(n_records=250, backends=("reference", "pallas"),
         data = _format_payload(fmt, n_records)
         entry = {"n_records": n_records, "bytes": len(data), "variants": {}}
         parsers = {}
-        labels = ["reference", "pallas", "pallas-fused"]
+        labels = ["reference", "pallas"]
+        if interpret_kernels():
+            labels.append("pallas-fused")
         if tuned:
             labels += [f"{b}-tuned" for b in backends]
         for label in labels:
@@ -676,7 +658,7 @@ def stream_sweep(n_records=250, backends=("reference", "pallas"),
     # megakernel per partition (fuse_pipeline=True), riding the same
     # StreamSession carry hooks — the fused-streaming accountability row.
     variants = list(backends)
-    if "pallas" in variants:
+    if "pallas" in variants and interpret_kernels():
         variants.append("pallas-fused")
     if tuned:
         # cache-resolved configs AND the cache's measured streaming
@@ -882,7 +864,7 @@ def _dist_variants(backends):
     if "reference" in backends:
         out.append("reference")
     if "pallas" in backends:
-        out += ["pallas", "pallas-fused"]
+        out += ["pallas", "pallas-fused"] if interpret_kernels() else ["pallas"]
     return out
 
 
@@ -890,12 +872,17 @@ def distributed_child(n_records, backends):
     """Runs INSIDE the per-D subprocess (``--_distributed-child``): the
     mesh-sharded end-to-end sweep on this process's device fleet, emitting
     one JSON object on stdout for the parent to aggregate."""
+    print(json.dumps(_distributed_on(jax.devices(), n_records, backends)))
+
+
+def _distributed_on(devices, n_records, backends):
+    """The mesh-sharded end-to-end sweep on a 1-D mesh over ``devices``."""
     from jax.sharding import Mesh
     from repro.core.distributed import DistributedParser
     from repro.launch.dryrun import parse_collective_bytes
 
-    mesh = Mesh(np.array(jax.devices()), ("data",))
-    out = {"devices": len(jax.devices()), "workloads": {}}
+    mesh = Mesh(np.array(devices), ("data",))
+    out = {"devices": len(devices), "workloads": {}}
     for kind, mk in (("yelp", yelp_parser), ("taxi", taxi_parser)):
         wl = {"variants": {}}
         for variant in _dist_variants(backends):
@@ -941,15 +928,18 @@ def distributed_child(n_records, backends):
                 "sharded_vs_single": bool(match),
             }
         out["workloads"][kind] = wl
-    print(json.dumps(out))
+    return out
 
 
 def distributed_sweep(n_records=250, backends=("reference", "pallas"),
                       devices=DIST_DEVICES):
     """Mesh-sharded end-to-end workload: GB/s over D ∈ {1, 2, 4, 8} virtual
-    devices on yelp + taxi, one subprocess per D (the host-platform device
-    override must be set before jax initialises, so it can never run in
-    this process).  Per variant the child also records the compiled
+    devices on yelp + taxi.  On the CPU platform each D runs in a
+    subprocess (the host-platform device override must be set before jax
+    initialises, so it can never run in this process); on an accelerator
+    this process already holds the chips, so a child could not reach them
+    and each D runs here, on a mesh over the first D devices.  Per variant
+    the sweep also records the compiled
     executable's collective byte/instruction counts (the O(D·|S|)
     accountability metric) and the ``sharded_vs_single`` bit-identity pin
     (``DistributedParser.assemble`` vs ``Parser.to_arrow``).  On this
@@ -963,7 +953,17 @@ def distributed_sweep(n_records=250, backends=("reference", "pallas"),
     backend_arg = ("all" if set(backends) >= {"reference", "pallas"}
                    else backends[0])
     entry = {"n_records_base": n_records, "per_device": {}}
+    in_process = jax.default_backend() != "cpu"
     for d in devices:
+        if in_process:
+            if d > len(jax.devices()):
+                entry["per_device"][f"D{d}"] = "skipped"
+                emit(f"distributed/D{d}", 0.0, "skipped")
+                continue
+            child = _distributed_on(jax.devices()[:d], n_records, backends)
+            entry["per_device"][f"D{d}"] = child
+            _emit_distributed(d, child)
+            continue
         env = dict(os.environ)
         kept = [f for f in env.get("XLA_FLAGS", "").split()
                 if not f.startswith("--xla_force_host_platform_device_count=")]
@@ -987,14 +987,18 @@ def distributed_sweep(n_records=250, backends=("reference", "pallas"),
             emit(f"distributed/D{d}", 0.0, "skipped")
             continue
         entry["per_device"][f"D{d}"] = child
-        for kind, wl in child["workloads"].items():
-            for variant, v in wl["variants"].items():
-                emit(f"distributed/D{d}/{kind}/{variant}",
-                     v["us_per_call"],
-                     f"{v['gbps']:.3f}GB/s;collective_bytes="
-                     f"{sum(v['collective_bytes'].values())};match="
-                     f"{v['sharded_vs_single']}")
+        _emit_distributed(d, child)
     return entry
+
+
+def _emit_distributed(d, child):
+    for kind, wl in child["workloads"].items():
+        for variant, v in wl["variants"].items():
+            emit(f"distributed/D{d}/{kind}/{variant}",
+                 v["us_per_call"],
+                 f"{v['gbps']:.3f}GB/s;collective_bytes="
+                 f"{sum(v['collective_bytes'].values())};match="
+                 f"{v['sharded_vs_single']}")
 
 
 def fig12_partition_size():
@@ -1124,6 +1128,7 @@ def tuned_regressions(report):
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--backend", default="all",
                     choices=["all", "reference", "pallas"])

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import dataset, emit, time_fn, yelp_parser
+from repro import compile_cache
 from repro.core import make_csv_dfa
 from repro.core import partition as partition_mod
 from repro.core import transition as tr
@@ -68,6 +69,7 @@ def typeconv_variants():
 
 
 def kernel_vs_ref():
+    from repro.core.backends import interpret_kernels
     from repro.kernels.dfa_scan import ops as kops
     from repro.kernels.dfa_scan import ref as kref
     dfa = make_csv_dfa()
@@ -75,13 +77,17 @@ def kernel_vs_ref():
     alphabet = np.frombuffer(b',"\nabcd ', np.uint8)
     chunks = jnp.asarray(
         alphabet[rng.integers(0, len(alphabet), size=4096 * 64)].reshape(4096, 64))
-    dt, _ = time_fn(lambda c: kops.chunk_vectors(c, dfa), chunks, iters=2)
-    emit("kernel/dfa_scan_interpret", dt * 1e6, "4096x64B;interpret-mode")
+    interpret = interpret_kernels()
+    dt, _ = time_fn(lambda c: kops.chunk_vectors(c, dfa, interpret=interpret),
+                    chunks, iters=2)
+    emit("kernel/dfa_scan" + ("_interpret" if interpret else ""), dt * 1e6,
+         "4096x64B" + (";interpret-mode" if interpret else ""))
     dt, _ = time_fn(lambda c: kref.chunk_vectors(c, dfa), chunks, iters=2)
     emit("kernel/dfa_scan_jnp_ref", dt * 1e6, "4096x64B")
 
 
 def run():
+    compile_cache.enable()
     scan_variants()
     partition_variants()
     typeconv_variants()

@@ -13,6 +13,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.configs import tuned_parser_config
 from repro.core import Parser, available_backends, formats
 
@@ -55,6 +56,7 @@ def tour(fmt: str, data: bytes, backend: str) -> None:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="reference",
                     choices=available_backends())

@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import Parser, ParserConfig, Schema, available_backends, make_csv_dfa
 
 CSV = (
@@ -26,6 +27,7 @@ CSV = (
 )
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="reference",
                     choices=available_backends())

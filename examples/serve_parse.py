@@ -26,6 +26,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ParserConfig, Schema, available_backends, make_csv_dfa
 from repro.data import synth
 from repro.serve import ParseService, TenantOverflow, TenantResult
@@ -40,6 +41,7 @@ def drain(tenant, out):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--records", type=int, default=200,
                     help="records per well-behaved tenant")

@@ -20,12 +20,14 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import Parser, ParserConfig, Schema, available_backends, make_csv_dfa
 from repro.core.streaming import StreamSession
 from repro.data import synth
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--records", type=int, default=20000,
                     help="yelp-like records per stream")

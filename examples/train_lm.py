@@ -17,6 +17,7 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.core import Schema
@@ -37,6 +38,7 @@ SIZES = {
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", default="tiny", choices=list(SIZES))
     ap.add_argument("--steps", type=int, default=200)

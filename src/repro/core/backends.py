@@ -49,24 +49,25 @@ Backends:
   * ``pallas``    — the Pallas TPU kernels (``kernels.dfa_scan`` /
     ``kernels.partition`` / ``kernels.numparse``).  The fused replay kernel
     makes the separate ``chunk_summaries`` jnp pass disappear; the
-    partition defaults to the single-pass radix kernel on real hardware
-    (``partition_impl="auto"`` → ``"kernel"``; under ``interpret=True`` it
-    resolves to the jit-fused jnp radix pass, with the kernel selectable
-    explicitly); and int32/float32/date columns convert
+    partition defaults to the single-pass radix kernel on a TPU
+    (``partition_impl="auto"`` → ``"kernel"``; off the chip the kernels run
+    interpreted and "auto" resolves to the jit-fused jnp radix pass, with
+    the kernel selectable explicitly); and int32/float32/date columns convert
     inside *fused gather+convert* ``numparse`` kernels that index the CSS
     in-kernel — no XLA ``take``/gather between the field index and
     conversion.  The fused kernels are *windowed* by default: each row
     block DMAs only its contiguous CSS window into VMEM (offsets within a
     column are sorted), so per-parse input is not capped by VMEM capacity;
-    ``cfg.window_rows`` / ``cfg.max_window_bytes`` size the windows,
-    ``window_rows=-1`` pins the whole-CSS kernels, and
+    ``cfg.window_rows`` / ``cfg.max_window_bytes`` size the windows, and
     ``cfg.fuse_typeconv=False`` restores the unfused gather+kernel path
     for comparison (``str`` stays the shared no-op — strings live in the
-    CSS and need no arithmetic).  ``cfg.interpret`` / ``cfg.block_chunks``
-    carry the kernel knobs.
+    CSS and need no arithmetic).  Whether the kernels run compiled or in
+    the Pallas interpreter is not a knob: :func:`interpret_kernels` derives
+    it from the platform (interpreted exactly when it is not a TPU), and
+    ``stages.plan_parse`` records the answer on ``ParsePlan.interpret``.
 
 Stage functions receive the ``ParserConfig`` duck-typed (``cfg.dfa``,
-``cfg.interpret``, ``cfg.block_chunks``, ``cfg.int_width``) so kernel knobs
+``cfg.block_chunks``, ``cfg.int_width``) so kernel knobs
 travel with the config instead of threading through every call site, and so
 this module never imports :mod:`repro.core.parser` (no cycle).
 
@@ -92,6 +93,14 @@ from repro.core.dfa import PAD_BYTE
 #: ``kernels.dfa_scan.dfa_scan.DEFAULT_BLOCK_CHUNKS`` without importing the
 #: kernel package at module load).
 DEFAULT_BLOCK_CHUNKS = 256
+
+
+def interpret_kernels() -> bool:
+    """The platform probe: Pallas kernels run in the interpreter exactly
+    when JAX's default platform is not a TPU (Mosaic compiles them only
+    there).  Every pallas stage reads this one function, so the plan and
+    the traced kernels always agree."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +189,7 @@ class ParseBackend:
     planning layer uses to resolve ``ParserConfig.partition_impl="auto"``
     and to fail fast on impls the backend does not implement;
     ``typeconv_path`` names the conversion strategy the config resolves to
-    (``reference`` / ``unfused`` / ``fused-windowed`` / ``fused-wholecss``)
+    (``reference`` / ``unfused`` / ``fused-windowed``)
     so plans and benchmark reports can label it without re-deriving the
     backend's dispatch logic.
     """
@@ -223,6 +232,12 @@ class ParseBackend:
     # already captures.  Two configs whose plan keys are equal must trace
     # to bit-identical executables — list knobs conservatively.
     config_key: Callable = lambda cfg: ()
+    # Whether this backend's kernels run in the Pallas interpreter; recorded
+    # on ``ParsePlan.interpret`` at plan time.
+    interpret: Callable[[], bool] = lambda: False
+    # Plan-time refusal of knob values this backend cannot build on the
+    # current platform: ``check_config(cfg)`` raises ValueError.
+    check_config: Callable = lambda cfg: None
 
 
 BACKENDS: Dict[str, ParseBackend] = {}
@@ -336,7 +351,7 @@ def _pl_chunk_vectors(chunks: jax.Array, cfg) -> jax.Array:
     bc = _block_chunks(cfg, chunks.shape[0])
     padded, n = pad_to_block(chunks, bc, PAD_BYTE)
     vecs = dfa_scan.chunk_vectors(
-        padded, cfg.dfa, block_chunks=bc, interpret=cfg.interpret
+        padded, cfg.dfa, block_chunks=bc, interpret=interpret_kernels()
     )
     return vecs[:n]
 
@@ -350,7 +365,8 @@ def _pl_replay_summaries(chunks: jax.Array, start: jax.Array, cfg):
         start.astype(jnp.int32), bc, cfg.dfa.start_state
     )
     classes, end_states, summ = dfa_scan.replay_fused(
-        padded, start_p, cfg.dfa, block_chunks=bc, interpret=cfg.interpret
+        padded, start_p, cfg.dfa, block_chunks=bc,
+        interpret=interpret_kernels(),
     )
     classes, end_states, summ = classes[:n], end_states[:n], summ[:n]
     summaries = offsets_mod.ChunkSummary(
@@ -369,7 +385,7 @@ def _pl_partition(col_tag, n_cols, impl, cfg) -> partition_mod.Partitioned:
     if bt:
         kw["block_tags"] = bt
     return partition_ops.partition_tags(
-        col_tag, n_cols, interpret=cfg.interpret, **kw
+        col_tag, n_cols, interpret=interpret_kernels(), **kw
     )
 
 
@@ -388,10 +404,11 @@ def _pl_parse_int(css, offset, length, cfg) -> typeconv_mod.Parsed:
 
     if not _fuse(cfg):
         return numparse_ops.parse_int_column(
-            css, offset, length, width=cfg.int_width, interpret=cfg.interpret)
+            css, offset, length, width=cfg.int_width,
+            interpret=interpret_kernels())
     return numparse_ops.parse_int_column_fused(
-        css, offset, length, width=cfg.int_width, interpret=cfg.interpret,
-        **_window_kw(cfg))
+        css, offset, length, width=cfg.int_width,
+        interpret=interpret_kernels(), **_window_kw(cfg))
 
 
 def _pl_parse_float(css, offset, length, cfg) -> typeconv_mod.Parsed:
@@ -399,10 +416,11 @@ def _pl_parse_float(css, offset, length, cfg) -> typeconv_mod.Parsed:
 
     if not _fuse(cfg):
         return numparse_ops.parse_float_column(
-            css, offset, length, width=cfg.float_width, interpret=cfg.interpret)
+            css, offset, length, width=cfg.float_width,
+            interpret=interpret_kernels())
     return numparse_ops.parse_float_column_fused(
-        css, offset, length, width=cfg.float_width, interpret=cfg.interpret,
-        **_window_kw(cfg))
+        css, offset, length, width=cfg.float_width,
+        interpret=interpret_kernels(), **_window_kw(cfg))
 
 
 def _pl_parse_date(css, offset, length, cfg) -> typeconv_mod.Parsed:
@@ -410,9 +428,9 @@ def _pl_parse_date(css, offset, length, cfg) -> typeconv_mod.Parsed:
 
     if not _fuse(cfg):
         return numparse_ops.parse_date_column(
-            css, offset, length, interpret=cfg.interpret)
+            css, offset, length, interpret=interpret_kernels())
     return numparse_ops.parse_date_column_fused(
-        css, offset, length, interpret=cfg.interpret, **_window_kw(cfg))
+        css, offset, length, interpret=interpret_kernels(), **_window_kw(cfg))
 
 
 def _pl_execute(raw_chunks, plan, cfg, initial_state, stitch=None):
@@ -464,7 +482,7 @@ def _pl_execute(raw_chunks, plan, cfg, initial_state, stitch=None):
         tagging=mat.tagging, n_cols=mat.n_cols, max_records=mat.max_records,
         selected=mat.selected, convert=mat.convert,
         int_width=cfg.int_width, float_width=cfg.float_width,
-        col_seed=col_seed, interpret=cfg.interpret,
+        col_seed=col_seed, interpret=interpret_kernels(),
     )
 
     if stitch is not None:
@@ -525,7 +543,7 @@ def _pl_execute(raw_chunks, plan, cfg, initial_state, stitch=None):
 def _pl_config_key(cfg) -> Tuple:
     """Pallas kernel knobs that shape traced code beyond the ParsePlan."""
     return (
-        "interpret", bool(cfg.interpret),
+        "interpret", interpret_kernels(),
         "block_chunks", getattr(cfg, "block_chunks", None),
         "fuse_typeconv", _fuse(cfg),
         "window_rows", getattr(cfg, "window_rows", 0),
@@ -537,14 +555,20 @@ def _pl_config_key(cfg) -> Tuple:
     )
 
 
-def _pl_typeconv_path(cfg) -> str:
-    if not _fuse(cfg):
-        return "unfused"
-    from repro.kernels.numparse import ops as numparse_ops
+def _pl_check_config(cfg) -> None:
+    """On a TPU, refuse the knob values whose kernels Mosaic cannot build."""
+    if interpret_kernels():
+        return
+    for knob in ("block_chunks", "window_rows", "partition_block_tags"):
+        value = getattr(cfg, knob, 0)
+        if value > 0 and value % 128:
+            raise ValueError(
+                f"{knob}={value}: on a TPU kernel blocks tile the lanes in "
+                f"multiples of 128")
 
-    if getattr(cfg, "window_rows", 0) == numparse_ops.WHOLE_CSS:
-        return "fused-wholecss"
-    return "fused-windowed"
+
+def _pl_typeconv_path(cfg) -> str:
+    return "fused-windowed" if _fuse(cfg) else "unfused"
 
 
 PALLAS = register_backend(ParseBackend(
@@ -559,14 +583,17 @@ PALLAS = register_backend(ParseBackend(
         "str": _shared_parse_str,
     },
     partition_impls=tuple(sorted(partition_mod.PARTITION_IMPLS)) + ("kernel",),
-    # "auto" resolution: the radix kernel when compiling for real hardware;
-    # under interpret=True (CPU containers/CI) the kernel runs op-by-op in
-    # the Pallas interpreter, where XLA's jit-fused radix pass (scatter2) is
-    # strictly faster — the kernel stays selectable (partition_impl="kernel")
-    # and is pinned bit-identical by the parity/fuzz/golden suites.
-    default_partition_impl=lambda cfg: "scatter2" if cfg.interpret else "kernel",
+    # "auto" resolution: the radix kernel on a TPU; off the chip the kernel
+    # runs op-by-op in the Pallas interpreter, where XLA's jit-fused radix
+    # pass (scatter2) is strictly faster — the kernel stays selectable
+    # (partition_impl="kernel") and is pinned bit-identical by the
+    # parity/fuzz/golden suites.
+    default_partition_impl=lambda cfg: (
+        "scatter2" if interpret_kernels() else "kernel"),
     typeconv_path=_pl_typeconv_path,
     config_key=_pl_config_key,
+    interpret=lambda: interpret_kernels(),
+    check_config=_pl_check_config,
     # whole-pipeline fusion (ParserConfig.fuse_pipeline=True): one
     # megakernel per partition, gated behind fused_max_bytes (the dataclass
     # default) with the staged composition above as the fallback tier

@@ -43,7 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import backends as backends_mod
 from repro.core import offsets as offsets_mod
@@ -256,7 +256,7 @@ class DistributedParser:
         self._fn = jax.jit(
             shard_map(
                 wrapped, mesh=mesh, in_specs=(spec_in,), out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
         )
 

@@ -23,6 +23,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scans
+
 _BIG = jnp.int32(2**31 - 1)
 
 
@@ -126,7 +128,7 @@ def field_index_terminated(
     # a column (stable partition), so an exclusive running max finds the
     # predecessor; with every field present this reduces to the plain
     # ``end[r-1] + 1`` recurrence bit-for-bit.
-    prev_end = jax.lax.cummax(jnp.where(present, end, -1), axis=1)
+    prev_end = scans.cummax(jnp.where(present, end, -1), axis=1)
     prev_end = jnp.concatenate(
         [jnp.full((n_cols, 1), -1, end.dtype), prev_end[:, :-1]], axis=1
     )

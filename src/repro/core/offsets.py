@@ -25,6 +25,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scans
 from repro.core.dfa import FIELD_DELIM, RECORD_DELIM
 
 REL = 0
@@ -49,15 +50,15 @@ def symbol_ids(classes: jax.Array) -> SymbolIds:
     is_rec = classes == RECORD_DELIM
     is_fld = classes == FIELD_DELIM
 
-    rec_incl = jnp.cumsum(is_rec.astype(jnp.int32))
+    rec_incl = scans.cumsum(is_rec.astype(jnp.int32))
     record_id = rec_incl - is_rec.astype(jnp.int32)  # exclusive
 
     # Column = (# field delims strictly before i) − (# field delims at or
     # before the last record delimiter strictly before i).
     idx = jnp.arange(n, dtype=jnp.int32)
-    fld_incl = jnp.cumsum(is_fld.astype(jnp.int32))
+    fld_incl = scans.cumsum(is_fld.astype(jnp.int32))
     fld_excl = fld_incl - is_fld.astype(jnp.int32)
-    last_rec_incl = jax.lax.cummax(jnp.where(is_rec, idx, -1))
+    last_rec_incl = scans.cummax(jnp.where(is_rec, idx, -1))
     last_rec_excl = jnp.concatenate([jnp.full((1,), -1, jnp.int32), last_rec_incl[:-1]])
     base = jnp.where(last_rec_excl >= 0, fld_incl[jnp.clip(last_rec_excl, 0)], 0)
     column_id = fld_excl - base
@@ -112,7 +113,7 @@ class ChunkOffsets(NamedTuple):
 def scan_chunk_offsets(summ: ChunkSummary) -> ChunkOffsets:
     """Exclusive scans giving each chunk its record and column offsets."""
     c = summ.rec_count.shape[0]
-    rec_off = jnp.cumsum(summ.rec_count) - summ.rec_count
+    rec_off = scans.cumsum(summ.rec_count) - summ.rec_count
 
     t_inc, o_inc = jax.lax.associative_scan(
         combine_col, (summ.col_tag, summ.col_off), axis=0
@@ -159,15 +160,15 @@ def symbol_ids_from_chunks(
     is_rec = classes == RECORD_DELIM
     is_fld = classes == FIELD_DELIM
 
-    rec_local_incl = jnp.cumsum(is_rec.astype(jnp.int32), axis=1)
+    rec_local_incl = scans.cumsum(is_rec.astype(jnp.int32), axis=1)
     rec_local_excl = rec_local_incl - is_rec.astype(jnp.int32)
     record_id = offs.rec_offset[:, None] + rec_local_excl
 
     pos = jnp.arange(k, dtype=jnp.int32)
-    fld_incl = jnp.cumsum(is_fld.astype(jnp.int32), axis=1)
+    fld_incl = scans.cumsum(is_fld.astype(jnp.int32), axis=1)
     fld_excl = fld_incl - is_fld.astype(jnp.int32)
     # Last record delimiter strictly before each position, within the chunk.
-    last_rec_incl = jax.lax.cummax(jnp.where(is_rec, pos[None, :], -1), axis=1)
+    last_rec_incl = scans.cummax(jnp.where(is_rec, pos[None, :], -1), axis=1)
     last_rec_excl = jnp.concatenate(
         [jnp.full((c, 1), -1, jnp.int32), last_rec_incl[:, :-1]], axis=1
     )

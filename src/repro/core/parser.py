@@ -106,9 +106,6 @@ class ParserConfig:
         Stage-implementation bundle: ``reference`` (pure jnp oracle) or
         ``pallas`` (TPU kernels); see ``core/backends.py``.  The registry
         is open — third-party backends register under new names.
-    ``interpret``
-        Run Pallas kernels in interpret mode (exact, op-by-op; the only
-        mode on CPU containers/CI).  Also steers ``partition_impl="auto"``.
     ``block_chunks``
         Chunks per Pallas grid step in the §3.1 DFA-scan kernels
         (``0`` = kernel default).
@@ -120,20 +117,16 @@ class ParserConfig:
         baseline.
     ``window_rows``
         pallas fused path: rows per CSS-window DMA block.  ``0`` uses the
-        numparse kernel default (512); ``-1`` disables windowing and pins
-        the whole-CSS-in-VMEM fused kernels (pre-window behaviour, capped
-        at VMEM capacity on real hardware — kept as the windowed path's
-        benchmark baseline).  Any positive value trades VMEM footprint
-        (smaller windows) against grid overhead (more steps).
+        numparse kernel default (512).  Any positive value trades VMEM
+        footprint (smaller windows) against grid overhead (more steps).
     ``max_window_bytes``
         pallas fused path: static CSS window tile in bytes.  ``0``
         auto-sizes from ``window_rows`` and the dtype's width (enough for
         every field ≤ width plus a terminator per row); explicit values are
         rounded up to the 128-byte lane alignment.  Columns whose fields
-        overflow the tile (a mega-field) fall back at run time — to the
-        whole-CSS fused kernel while the CSS is statically small, else to
-        per-row windows — so the fallback never compiles an
-        unbounded-VMEM kernel either.
+        overflow the tile (a mega-field) fall back at run time to per-row
+        windows, so the fallback never compiles an unbounded-VMEM kernel
+        either.
     ``fuse_pipeline``
         pallas backend: run the whole replay→tag→partition→convert
         composition as ONE megakernel per partition
@@ -177,7 +170,6 @@ class ParserConfig:
     float_width: int = 24
     validate_columns: bool = False
     backend: str = "reference"       # reference | pallas (core/backends.py)
-    interpret: bool = True           # Pallas interpret mode (CPU container)
     block_chunks: int = 0            # pallas DFA-scan grid: chunks per step
                                      # (0 = kernel default)
     fuse_typeconv: bool = True       # pallas: fused gather+convert kernels

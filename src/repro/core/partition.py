@@ -74,7 +74,7 @@ def partition_scatter2(col_tag: jax.Array, n_cols: int,
     (~12·N·C bytes of traffic).  Blocking bounds intra-block ranks by
     ``block`` ≤ 255 so they fit uint8 (~2·N·C bytes), and the inter-block
     scan shrinks to (N/block × C) int32 — a ~6× traffic cut on the
-    partition step (EXPERIMENTS.md §Perf, parser iteration 1).
+    partition step.
     """
     n = col_tag.shape[0]
     assert block < 256, "intra-block ranks must fit uint8"

@@ -106,22 +106,22 @@ class MaterializePlan(NamedTuple):
     selected: Optional[Tuple[bool, ...]]        # None = every column selected
     convert: Tuple[Tuple[str, int, str], ...]   # (name, schema index, dtype)
     typeconv_path: str = "reference"            # reference | unfused |
-                                                # fused-windowed | fused-wholecss
+                                                # fused-windowed
 
 
 def plan_materialize(cfg, backend: ParseBackend, *, convert: bool = True
                      ) -> MaterializePlan:
     """Resolve ``cfg`` into a :class:`MaterializePlan` for ``backend``.
 
-    ``partition_impl="auto"`` becomes the backend's config-aware default
-    (on ``pallas``: the radix kernel when compiling for real hardware, the
-    jit-fused jnp radix pass under ``interpret=True``); explicit impls are
+    ``partition_impl="auto"`` becomes the backend's platform-aware default
+    (on ``pallas``: the radix kernel on a TPU, the jit-fused jnp radix pass
+    where the kernels run interpreted); explicit impls are
     validated against ``backend.partition_impls`` so typos and
     backend-foreign impls fail at config time, not under jit.  The
     windowed-DMA knobs (``cfg.window_rows`` / ``cfg.max_window_bytes``,
     pallas fused path) are range-checked here for the same reason, and the
     resolved conversion strategy is recorded as ``plan.typeconv_path``
-    (``reference`` / ``unfused`` / ``fused-windowed`` / ``fused-wholecss``)
+    (``reference`` / ``unfused`` / ``fused-windowed``)
     so benchmarks and debug output can name the path a config actually
     runs.  With ``convert=False`` the plan builds the CSS + field index
     only (the distributed driver's per-shard contract).
@@ -135,10 +135,9 @@ def plan_materialize(cfg, backend: ParseBackend, *, convert: bool = True
             f"{backend.name!r}; available: {backend.partition_impls}"
         )
     window_rows = getattr(cfg, "window_rows", 0)
-    if window_rows < -1:
+    if window_rows < 0:
         raise ValueError(
-            f"window_rows must be ≥ -1 (-1 = whole-CSS fused kernels, "
-            f"0 = kernel default), got {window_rows}"
+            f"window_rows must be ≥ 0 (0 = kernel default), got {window_rows}"
         )
     max_window_bytes = getattr(cfg, "max_window_bytes", 0)
     if max_window_bytes < 0:
@@ -156,6 +155,7 @@ def plan_materialize(cfg, backend: ParseBackend, *, convert: bool = True
             f"fused_max_bytes must be ≥ 0 (0 = backend default), "
             f"got {cfg.fused_max_bytes}"
         )
+    backend.check_config(cfg)
     selected = None
     if not all(c.selected for c in cfg.schema.columns):
         selected = tuple(bool(c.selected) for c in cfg.schema.columns)
@@ -211,6 +211,10 @@ class ParsePlan(NamedTuple):
     plan at construction time so typos fail fast and every partition of a
     stream reuses one executable.
 
+    ``interpret`` records whether the backend's Pallas kernels run in the
+    interpreter (derived from the platform by
+    ``backends.interpret_kernels``, never configured).
+
     ``execute_path`` records the *resolved* execution tier (``"staged"`` =
     the stage composition below; ``"fused"`` = the backend's whole-pipeline
     ``execute`` override, still subject to the trace-time
@@ -223,6 +227,7 @@ class ParsePlan(NamedTuple):
     expected_columns: Optional[int]   # None = skip the §4.3 column-count check
     execute_path: str = "staged"      # staged | fused
     path_reason: str = "fuse_pipeline not requested"
+    interpret: bool = False           # Pallas kernels interpreted (off-TPU)
 
 
 class ParseStitch(NamedTuple):
@@ -275,7 +280,9 @@ def plan_parse(cfg, backend: ParseBackend, *, convert: bool = True) -> ParsePlan
     tiers are part of the design (mirroring the windowed numparse kernels):
     backends without a fused executor, and index-only plans (the megakernel
     produces typed columns, which ``convert=False`` drivers must not pay
-    for), stay staged.
+    for), stay staged.  On a TPU the request is refused outright: the
+    megakernel has no Mosaic lowering yet, and silently running the staged
+    path (or the interpreter) in its place would misreport what ran.
     """
     # Fail fast on malformed DFA tables (a hand-rolled or third-party
     # format whose groups/PAD/record-delimiter contract is broken would
@@ -283,8 +290,15 @@ def plan_parse(cfg, backend: ParseBackend, *, convert: bool = True) -> ParsePlan
     # Registered formats (core/formats.py) were validated at registration;
     # this covers configs built around ad-hoc Dfa instances too.
     cfg.dfa.validate_tables()
+    interpret = backend.interpret()
     path, reason = "staged", "fuse_pipeline not requested"
     if getattr(cfg, "fuse_pipeline", False):
+        if backend.execute is not None and not interpret:
+            raise ValueError(
+                "fuse_pipeline=True: the whole-pipeline megakernel "
+                "(kernels/fused_pipeline) has no Mosaic lowering for the TPU "
+                "yet; use the staged pallas path (fuse_pipeline=False)"
+            )
         if backend.execute is None:
             reason = f"backend {backend.name!r} has no fused executor"
         elif not convert:
@@ -296,6 +310,7 @@ def plan_parse(cfg, backend: ParseBackend, *, convert: bool = True) -> ParsePlan
         expected_columns=cfg.schema.n_cols if cfg.validate_columns else None,
         execute_path=path,
         path_reason=reason,
+        interpret=interpret,
     )
 
 
@@ -527,7 +542,7 @@ def materialize(
     part = backend.partition(tagged.col_tag, n_cols, plan.partition_impl, cfg)
     if plan.tagging == "tagged":
         # delim_flag is structurally all-False in tagged mode: skip one
-        # N-sized gather+write (EXPERIMENTS.md §Perf parser iteration)
+        # N-sized gather+write
         css, rec_sorted, col_sorted = partition_mod.apply_partition(
             part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag
         )

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import stages as stages_mod
 from repro.core.dfa import PAD_BYTE
@@ -330,11 +330,11 @@ class StreamSession:
         if self.mesh is not None:
             # Lane sharding: every in/out leaf is partitioned on its leading
             # stream axis; each device runs the SAME vmapped step over its
-            # own S/D lanes.  check_rep=False: nothing is replicated.
+            # own S/D lanes.  check_vma=False: nothing is replicated.
             spec = PartitionSpec(self.mesh_axis)
             fn = shard_map(fn, mesh=self.mesh,
                            in_specs=(spec, spec, spec, spec, spec),
-                           out_specs=spec, check_rep=False)
+                           out_specs=spec, check_vma=False)
         # Donate the carry buffers: partition i+1's step overwrites partition
         # i's carry in place (no device-side copy growth).  CPU/interpret
         # hosts can't alias donations — skip there to keep runs warning-free.

@@ -18,7 +18,7 @@ Two interchangeable composite implementations are provided:
     composition "apply a, then b" is exactly ``A @ B``.
 
 Which one wins is workload/hardware dependent; ``benchmarks/bench_scan.py``
-and EXPERIMENTS.md §Perf carry the measurements.
+measures both.
 """
 from __future__ import annotations
 
@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scans
 from repro.core.dfa import Dfa
 
 
@@ -50,9 +51,16 @@ def compose(a: jax.Array, b: jax.Array) -> jax.Array:
     """Composite of state-transition vectors: ``(a ∘ b)[s] = b[a[s]]``.
 
     Shapes ``(..., S)``; leading dims broadcast elementwise (as required by
-    ``lax.associative_scan``).
+    ``lax.associative_scan``).  A select over the |S| states rather than a
+    gather: elementwise work that XLA compiles quickly for the TPU at any
+    chunk count, where the gather form took 30 s for 65,536 chunks
+    (compiled on an 8-core x86 host).
     """
-    return jnp.take_along_axis(b, a.astype(jnp.int32), axis=-1)
+    a = a.astype(jnp.int32)
+    out = jnp.zeros(jnp.broadcast_shapes(a.shape, b.shape), b.dtype)
+    for t in range(b.shape[-1]):
+        out = jnp.where(a == t, b[..., t:t + 1], out)
+    return out
 
 
 def vectors_to_matrices(vecs: jax.Array, n_states: int, dtype=jnp.float32) -> jax.Array:
@@ -108,7 +116,7 @@ def exclusive_scan_vectors(vecs: jax.Array, use_matmul: bool = False) -> jax.Arr
         inc = jax.lax.associative_scan(compose_matmul, mats, axis=0)
         inc = matrices_to_vectors(inc)
     else:
-        inc = jax.lax.associative_scan(compose, vecs, axis=0)
+        inc = scans.scan(compose, vecs, identity_vector(n_states))
     ident = jnp.broadcast_to(identity_vector(n_states), (1, n_states))
     return jnp.concatenate([ident, inc[:-1]], axis=0)
 

@@ -24,6 +24,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _ZERO = ord("0")
 _POW10_I32 = jnp.array([10**k for k in range(10)], jnp.int32)
@@ -171,6 +172,25 @@ def parse_int_segmented(css: jax.Array, field_start: jax.Array, field_id: jax.Ar
     return Parsed(sign * value, valid, ~has_bytes)
 
 
+#: float32 powers of ten, correctly rounded; below 1e-37 float32 is
+#: subnormal and flushes to zero, above 1e38 it overflows.
+_POW10_MIN, _POW10_MAX = -37, 38
+
+
+def pow10(exp):
+    """``10.0 ** exp`` in float32 for an int32 ``exp``, from a table of
+    correctly rounded constants (0 below, inf above its range).
+
+    A select chain rather than ``jnp.power``: the Pallas kernels and XLA
+    lower ``pow`` differently on a TPU, and both paths must agree to the
+    bit; the table also matches XLA's CPU ``pow`` on every exponent.
+    """
+    out = jnp.where(exp > _POW10_MAX, jnp.float32(np.inf), jnp.float32(0.0))
+    for k in range(_POW10_MIN, _POW10_MAX + 1):
+        out = jnp.where(exp == k, jnp.float32(10.0 ** k), out)
+    return out
+
+
 def parse_float(css, offset, length, width: int = 24) -> Parsed:
     """Float32 parse: ``[+-]ddd[.ddd][eE[+-]dd]`` with masked vector passes."""
     raw, mask = gather_field_bytes(css, offset, length, width)
@@ -228,7 +248,7 @@ def parse_float(css, offset, length, width: int = 24) -> Parsed:
         exp_step, jnp.zeros(b.shape[0], jnp.int32), (de.T, exp_digit.T)
     )
     exp = jnp.where(e_neg, -eacc, eacc) - frac_digits
-    value = sign.astype(jnp.float32) * macc * jnp.power(jnp.float32(10.0), exp.astype(jnp.float32))
+    value = sign.astype(jnp.float32) * macc * pow10(exp)
 
     empty = length == 0
     ok &= length <= width

@@ -13,6 +13,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scans
 from repro.core.dfa import FIELD_DELIM, RECORD_DELIM, Dfa
 
 
@@ -115,7 +116,7 @@ def chunk_colcount_summary(classes: jax.Array) -> ColCountSummary:
 
     # Complete records inside the chunk: count fields between consecutive
     # in-chunk record delimiters.
-    rec_idx = jnp.cumsum(is_rec.astype(jnp.int32), axis=1) - is_rec
+    rec_idx = scans.cumsum(is_rec.astype(jnp.int32), axis=1) - is_rec
     fld_per = jax.vmap(
         lambda f, r: jax.ops.segment_sum(f.astype(jnp.int32), r, num_segments=k + 1)
     )(is_fld, jnp.where(is_rec, rec_idx, k))

@@ -22,7 +22,7 @@ from repro.kernels.dfa_scan import dfa_scan
     jax.jit, static_argnames=("dfa", "block_chunks", "interpret")
 )
 def chunk_vectors(chunks, dfa: Dfa, block_chunks: int = dfa_scan.DEFAULT_BLOCK_CHUNKS,
-                  interpret: bool = True):
+                  *, interpret: bool):
     return dfa_scan.chunk_vectors(chunks, dfa, block_chunks=block_chunks,
                                   interpret=interpret)
 
@@ -32,7 +32,7 @@ def chunk_vectors(chunks, dfa: Dfa, block_chunks: int = dfa_scan.DEFAULT_BLOCK_C
 )
 def replay(chunks, start_states, dfa: Dfa,
            block_chunks: int = dfa_scan.DEFAULT_BLOCK_CHUNKS,
-           interpret: bool = True):
+           *, interpret: bool):
     return dfa_scan.replay(chunks, start_states, dfa,
                            block_chunks=block_chunks, interpret=interpret)
 
@@ -42,7 +42,7 @@ def replay(chunks, start_states, dfa: Dfa,
 )
 def parse_classes(chunks, dfa: Dfa,
                   block_chunks: int = dfa_scan.DEFAULT_BLOCK_CHUNKS,
-                  interpret: bool = True, use_matmul: bool = False):
+                  *, interpret: bool, use_matmul: bool = False):
     """Kernel-backed context determination + replay (paper §3.1 end to end)."""
     vecs = dfa_scan.chunk_vectors(chunks, dfa, block_chunks=block_chunks,
                                   interpret=interpret)
@@ -59,7 +59,7 @@ def parse_classes(chunks, dfa: Dfa,
 )
 def replay_fused(chunks, start_states, dfa: Dfa,
                  block_chunks: int = dfa_scan.DEFAULT_BLOCK_CHUNKS,
-                 interpret: bool = True):
+                 *, interpret: bool):
     """Fused replay + paper-§3.2 chunk summaries in one VMEM pass."""
     return dfa_scan.replay_fused(chunks, start_states, dfa,
                                  block_chunks=block_chunks,
@@ -71,7 +71,7 @@ def replay_fused(chunks, start_states, dfa: Dfa,
 )
 def parse_contexts(chunks, dfa: Dfa,
                    block_chunks: int = dfa_scan.DEFAULT_BLOCK_CHUNKS,
-                   interpret: bool = True, use_matmul: bool = False,
+                   *, interpret: bool, use_matmul: bool = False,
                    initial_state=None):
     """Kernel-backed §3.1 + fused §3.2: context determination, replay, and
     per-chunk offset summaries — ``parse_classes`` upgraded to the fused

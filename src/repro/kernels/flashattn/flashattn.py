@@ -93,7 +93,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """``q (B, Hq, Sq, D)``, ``k/v (B, Hkv, Skv, D)`` → ``(B, Hq, Sq, D)``.
 

@@ -15,7 +15,7 @@ from repro.kernels.flashattn import flashattn
 def flash_attention(q, k, v, causal: bool = True, window=None, scale=None,
                     block_q: int = flashattn.DEFAULT_BLOCK_Q,
                     block_kv: int = flashattn.DEFAULT_BLOCK_KV,
-                    interpret: bool = True):
+                    *, interpret: bool):
     return flashattn.flash_attention(
         q, k, v, causal=causal, window=window, scale=scale,
         block_q=block_q, block_kv=block_kv, interpret=interpret,

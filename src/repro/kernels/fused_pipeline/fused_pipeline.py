@@ -242,19 +242,19 @@ def _make_pipeline_kernel(
         # -- 6. typed conversion (shared numparse cores, in-kernel offsets) -
         for i, (c, dtype, width) in enumerate(convert):
             # Width-pad so offset + lane never leaves the buffer (offsets of
-            # empty/padding rows clamp to [0, N]) — same contract as the
-            # staged fused kernels (numparse._fused_call).
+            # empty/padding rows clamp to [0, N]).
             css_pad = jnp.concatenate([css, jnp.zeros((width,), jnp.uint8)])
             off_c = jnp.clip(offset[c], 0, N)
             ln_c = length[c]
             lane = jax.lax.broadcasted_iota(jnp.int32, (M, width), 1)
             b = css_pad[off_c[:, None] + lane].astype(jnp.int32)
+            cols = [b[:, w] for w in range(width)]
             if dtype == "int32":
-                val, ok = cores._int_arith(b, ln_c, M, width)
+                val, ok = cores._int_arith(cols, ln_c)
             elif dtype == "float32":
-                val, ok = cores._float_arith(b, ln_c, M, width)
+                val, ok = cores._float_arith(cols, ln_c)
             else:  # date
-                val, ok = cores._date_arith(b, ln_c, M)
+                val, ok = cores._date_arith(cols, ln_c)
             val_refs[2 * i][...] = val[None, :]
             val_refs[2 * i + 1][...] = ok.astype(jnp.int32)[None, :]
 
@@ -291,7 +291,7 @@ def pipeline_call(
     selected,
     convert,
     col_seed=None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Run the megakernel over one partition.
 

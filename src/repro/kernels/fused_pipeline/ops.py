@@ -62,7 +62,7 @@ def fused_parse(
     int_width: int,
     float_width: int,
     col_seed=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> FusedParse:
     """One partition through the megakernel (see module docstring).
 

@@ -17,14 +17,11 @@ Two families of field-index entry points:
     VMEM capacity.  When the plan detects a window the static tile cannot
     hold (a mega-field longer than the tile) or offsets that are not
     monotone (the sortedness contract violated by a hand-built index),
-    the column falls back under ``lax.cond`` — to the whole-CSS fused
-    kernel while the CSS is statically small
-    (:data:`WHOLECSS_FALLBACK_MAX_BYTES`), else to per-row windows
-    (``rows_per_block=1`` — correct for arbitrary offsets, still
-    ``O(width)`` VMEM), so the windowed path never *compiles* a kernel
-    whose VMEM block grows with the CSS.  Same arithmetic, same results
-    on every branch.  ``window_rows=WHOLE_CSS`` (−1) disables windowing
-    outright (the benchmark baseline for the window DMA).
+    the column falls back under ``lax.cond`` to per-row windows (each
+    field DMAs its own small aligned window — correct for arbitrary
+    offsets), so the windowed path never *compiles* a kernel whose VMEM
+    block grows with the CSS, and both branches lower for the TPU.  Same
+    arithmetic, same results on every branch.
   * ``parse_*_column``       — the unfused path: gather a column's field
     bytes out of the CSS with XLA's gather and hand the dense ``(R, W)``
     matrix to the arithmetic kernel.  Kept as the ``cfg.fuse_typeconv=False``
@@ -41,14 +38,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import scans
 from repro.core import typeconv as typeconv_mod
 from repro.core.backends import pad_to_block
 from repro.kernels.numparse import numparse
-
-#: ``window_rows`` sentinel: disable windowing, run the whole-CSS fused
-#: kernels unconditionally (PR-3 behaviour; the windowed path's baseline).
-WHOLE_CSS = -1
-
 
 def auto_window_bytes(rows_per_block: int, width: int) -> int:
     """Static CSS window tile (bytes) for ``rows_per_block`` fields.
@@ -60,7 +53,7 @@ def auto_window_bytes(rows_per_block: int, width: int) -> int:
     :data:`numparse.WINDOW_ALIGN` granule absorbs the align-down of the
     window start, and the total rounds up to the alignment.  Fields longer
     than ``width`` (unparseable anyway) may exceed this and take the
-    whole-CSS fallback at run time.
+    per-row fallback at run time.
     """
     need = rows_per_block * (width + 1) + width + numparse.WINDOW_ALIGN
     a = numparse.WINDOW_ALIGN
@@ -99,7 +92,7 @@ def plan_css_windows(offset, length, *, rows_per_block: int, width: int,
     # Seed for empty rows: the first (= minimum, offsets sorted) non-empty
     # offset, so leading empties inherit forward; css_len if all empty.
     seed = jnp.min(jnp.where(nonempty, off_c, css_len))
-    eff = jax.lax.cummax(jnp.where(nonempty, off_c, seed))
+    eff = scans.cummax(jnp.where(nonempty, off_c, seed))
     monotone = jnp.all(jnp.where(nonempty, off_c == eff, True))
     eff_blocks = eff.reshape(nb, rows_per_block)
     a = numparse.WINDOW_ALIGN
@@ -116,7 +109,7 @@ def plan_css_windows(offset, length, *, rows_per_block: int, width: int,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def parse_int_fields(field_bytes, lengths,
                      block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
-                     interpret: bool = True):
+                     *, interpret: bool):
     return numparse.parse_int_fields(
         field_bytes, lengths, block_rows=block_rows, interpret=interpret
     )
@@ -125,7 +118,7 @@ def parse_int_fields(field_bytes, lengths,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def parse_float_fields(field_bytes, lengths,
                        block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
-                       interpret: bool = True):
+                       *, interpret: bool):
     return numparse.parse_float_fields(
         field_bytes, lengths, block_rows=block_rows, interpret=interpret
     )
@@ -134,7 +127,7 @@ def parse_float_fields(field_bytes, lengths,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def parse_date_fields(field_bytes, lengths,
                       block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
-                      interpret: bool = True):
+                      *, interpret: bool):
     return numparse.parse_date_fields(
         field_bytes, lengths, block_rows=block_rows, interpret=interpret
     )
@@ -154,7 +147,7 @@ def _gather_and_run(kernel_fn, css, offset, length, width, block_rows, interpret
 @functools.partial(jax.jit, static_argnames=("width", "block_rows", "interpret"))
 def parse_int_column(css, offset, length, width: int = 11,
                      block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
-                     interpret: bool = True) -> typeconv_mod.Parsed:
+                     *, interpret: bool) -> typeconv_mod.Parsed:
     """Kernel-backed equivalent of ``typeconv.parse_int``."""
     return _gather_and_run(numparse.parse_int_fields, css, offset, length,
                            width, block_rows, interpret)
@@ -163,7 +156,7 @@ def parse_int_column(css, offset, length, width: int = 11,
 @functools.partial(jax.jit, static_argnames=("width", "block_rows", "interpret"))
 def parse_float_column(css, offset, length, width: int = 24,
                        block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
-                       interpret: bool = True) -> typeconv_mod.Parsed:
+                       *, interpret: bool) -> typeconv_mod.Parsed:
     """Kernel-backed equivalent of ``typeconv.parse_float`` (bit-identical)."""
     return _gather_and_run(numparse.parse_float_fields, css, offset, length,
                            width, block_rows, interpret)
@@ -172,7 +165,7 @@ def parse_float_column(css, offset, length, width: int = 24,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def parse_date_column(css, offset, length,
                       block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
-                      interpret: bool = True) -> typeconv_mod.Parsed:
+                      *, interpret: bool) -> typeconv_mod.Parsed:
     """Kernel-backed equivalent of ``typeconv.parse_date`` (bit-identical)."""
     return _gather_and_run(numparse.parse_date_fields, css, offset, length,
                            numparse.DATE_WIDTH, block_rows, interpret)
@@ -182,146 +175,141 @@ def parse_date_column(css, offset, length,
 # fused gather+convert entry points (the kernel owns the CSS indexing)
 # ---------------------------------------------------------------------------
 
-def _resolve_window(window_rows, window_bytes, block_rows, width, r):
+def _resolve_window(window_rows, window_bytes, width, r):
     """Static window geometry: (rows per window block, window tile bytes)."""
-    br = min(window_rows or block_rows, r)
+    br = min(window_rows or numparse.DEFAULT_WINDOW_ROWS, r)
     wt = window_bytes or auto_window_bytes(br, width)
     a = numparse.WINDOW_ALIGN
     wt = max(-(-wt // a) * a, -(-(width + a) // a) * a)  # ≥ width + align
     return br, wt
 
 
-#: Static ceiling (bytes) for the whole-CSS *fallback* of the windowed
-#: path.  A CSS at most this big may be compiled as a single VMEM block for
-#: the mega-field/non-monotone fallback branch (fast, one grid sweep);
-#: beyond it the fallback switches to per-row windows (``rows_per_block=1``
-#: — correct for arbitrary offsets, VMEM-bounded, slower), so no kernel
-#: with an unbounded VMEM block is ever *compiled*, keeping the windowed
-#: path's VMEM usage bounded regardless of CSS size.  Well under the
-#: ~16 MB/core VMEM budget, leaving room for outputs and double-buffering.
-WHOLECSS_FALLBACK_MAX_BYTES = 4 << 20
+def _plan_column(offset, length, width, window_rows, window_bytes, css_len):
+    """Pad a column to whole window blocks and plan its windows:
+    ``(br, wt, off_p, len_p, r, win_start, rel, fits)``."""
+    br, wt = _resolve_window(window_rows, window_bytes, width, offset.shape[0])
+    off_p, r = pad_to_block(offset.astype(jnp.int32), br, 0)
+    len_p, _ = pad_to_block(length.astype(jnp.int32), br, 0)
+    win_start, rel, fits = plan_css_windows(
+        off_p, len_p, rows_per_block=br, width=width, window_bytes=wt,
+        css_len=css_len,
+    )
+    return br, wt, off_p, len_p, r, win_start, rel, fits
 
 
-def _per_row_windows(css_len, offset, width):
-    """Degenerate per-row window plan: one window per field.
+def takes_per_row_fallback(css_len: int, offset, length, width: int,
+                           window_rows: int = 0, window_bytes: int = 0):
+    """``() bool``: whether the fused converter runs this column's
+    per-row-window fallback (its windows overflow the static tile, or its
+    offsets are not monotone) under the given window geometry."""
+    return ~_plan_column(offset, length, width, window_rows, window_bytes,
+                         css_len)[-1]
 
-    Each row's window depends only on its own offset — no sortedness, no
-    mega-field sensitivity (only ``width`` bytes are ever read per field) —
-    so this plan is correct for *arbitrary* ``(offset, length)`` while
-    keeping the VMEM block at ``O(width)``.  The universal fallback when
-    the CSS is too large for the whole-CSS fallback kernel.
+
+def _windowed_or_per_row(fits, windowed, per_row, operands):
+    """``lax.cond(fits, windowed, per_row)`` over ``operands`` that stays a
+    branch under ``vmap``.
+
+    ``vmap`` turns a ``cond`` on a batched predicate into a select that runs
+    both branches, so every batched partition (the streaming engine and the
+    service vmap the whole parse step) would pay for the per-row kernel.
+    The batching rule instead runs the windowed kernel on every lane and
+    the per-row kernel only when some lane's windows do not fit, keeping
+    each lane's own choice.
     """
-    a = numparse.WINDOW_ALIGN
-    wt1 = -(-(width + a) // a) * a          # align slop + width fits
-    off_c = jnp.clip(offset.astype(jnp.int32), 0, css_len)
-    ws1 = (off_c // a) * a
-    return ws1, off_c - ws1, wt1
+
+    @jax.custom_batching.custom_vmap
+    def pick(fits, *ops):
+        return jax.lax.cond(fits, lambda: windowed(*ops),
+                            lambda: per_row(*ops))
+
+    @pick.def_vmap
+    def _pick_lanes(axis_size, in_batched, fits, *ops):
+        axes = tuple(0 if b else None for b in in_batched[1:])
+        lanes_fit = (fits if in_batched[0]
+                     else jnp.broadcast_to(fits, (axis_size,)))
+        w = jax.vmap(windowed, in_axes=axes, axis_size=axis_size)(*ops)
+
+        def mixed():
+            p = jax.vmap(per_row, in_axes=axes, axis_size=axis_size)(*ops)
+            return tuple(jnp.where(lanes_fit[:, None], a, b)
+                         for a, b in zip(w, p))
+
+        out = jax.lax.cond(jnp.all(lanes_fit), lambda: w, mixed)
+        return out, (True, True)
+
+    return pick(fits, *operands)
 
 
-def _fused_column(whole_fn, windowed_fn, css, offset, length, width,
-                  block_rows, window_rows, window_bytes, interpret,
-                  wholecss_max=None):
+def _fused_column(windowed_fn, per_row_fn, css, offset, length, width,
+                  val_dtype, window_rows, window_bytes, interpret):
     """Shared fused-column body: windowed by default, bounded fallback.
 
-    ``whole_fn(css, off, len, block_rows=, interpret=)`` and
     ``windowed_fn(css, rel, len, win_start, block_rows=, window_bytes=,
-    interpret=)`` arrive with any dtype-specific ``width`` already bound.
-    ``window_rows == WHOLE_CSS`` skips planning entirely; otherwise the
-    window plan decides at run time (``lax.cond``) between the windowed
-    kernel and a fallback for degenerate shapes (mega-fields overflowing
-    the static tile, non-monotone offsets).  The fallback itself is chosen
-    *statically* by CSS size so no unbounded-VMEM kernel is ever compiled:
-    at most ``wholecss_max`` bytes (default
-    :data:`WHOLECSS_FALLBACK_MAX_BYTES`) the whole-CSS kernel; above that,
-    per-row windows (:func:`_per_row_windows` — correct for arbitrary
-    offsets, ``O(width)`` VMEM, one grid step per field).  Every branch
-    shares the arithmetic, so the choice never changes results, only
-    footprint and speed.
+    interpret=)`` and ``per_row_fn(css, off, len, interpret=)`` arrive with
+    any dtype-specific ``width`` already bound.  The window plan decides at
+    run time between the windowed kernel and per-row windows for
+    degenerate shapes (mega-fields overflowing the static tile,
+    non-monotone offsets): each field then DMAs its own aligned window,
+    correct for arbitrary offsets with a bounded VMEM footprint.  Both
+    kernels share the arithmetic, so the choice never changes results,
+    only footprint and speed.
     """
-    if wholecss_max is None:
-        wholecss_max = WHOLECSS_FALLBACK_MAX_BYTES
     r0 = offset.shape[0]
     if r0 == 0:  # degenerate but public: no fields to convert
         zb = jnp.zeros((0,), bool)
-        return typeconv_mod.Parsed(
-            whole_fn(css, offset, length, block_rows=block_rows,
-                     interpret=interpret)[0], zb, zb)
-    if window_rows == WHOLE_CSS:
-        br = min(block_rows, r0)
-        off_p, r = pad_to_block(offset.astype(jnp.int32), br, 0)
-        len_p, _ = pad_to_block(length.astype(jnp.int32), br, 0)
-        val, ok = whole_fn(css, off_p, len_p, block_rows=br,
-                           interpret=interpret)
-    else:
-        br, wt = _resolve_window(window_rows, window_bytes, block_rows,
-                                 width, r0)
-        off_p, r = pad_to_block(offset.astype(jnp.int32), br, 0)
-        len_p, _ = pad_to_block(length.astype(jnp.int32), br, 0)
-        win_start, rel, fits = plan_css_windows(
-            off_p, len_p, rows_per_block=br, width=width, window_bytes=wt,
-            css_len=css.shape[0],
-        )
-        if css.shape[0] + width <= wholecss_max:  # static: shapes, not data
-            fallback = lambda: whole_fn(css, off_p, len_p, block_rows=br,
-                                        interpret=interpret)
-        else:
-            ws1, rel1, wt1 = _per_row_windows(css.shape[0], off_p, width)
-            fallback = lambda: windowed_fn(css, rel1, len_p, ws1,
-                                           block_rows=1, window_bytes=wt1,
-                                           interpret=interpret)
-        val, ok = jax.lax.cond(
-            fits,
-            lambda: windowed_fn(css, rel, len_p, win_start, block_rows=br,
-                                window_bytes=wt, interpret=interpret),
-            fallback,
-        )
+        return typeconv_mod.Parsed(jnp.zeros((0,), val_dtype), zb, zb)
+    br, wt, off_p, len_p, r, win_start, rel, fits = _plan_column(
+        offset, length, width, window_rows, window_bytes, css.shape[0])
+    val, ok = _windowed_or_per_row(
+        fits,
+        lambda c, o, ln, ro, ws: windowed_fn(
+            c, ro, ln, ws, block_rows=br, window_bytes=wt,
+            interpret=interpret),
+        lambda c, o, ln, ro, ws: per_row_fn(c, o, ln, interpret=interpret),
+        (css, off_p, len_p, rel, win_start))
     val, ok = val[:r], ok[:r]
     empty = length == 0
     return typeconv_mod.Parsed(val, ok & ~empty, empty)
 
 
-_FUSED_STATICS = ("width", "block_rows", "window_rows", "window_bytes",
-                  "interpret")
+_FUSED_STATICS = ("width", "window_rows", "window_bytes", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_FUSED_STATICS)
 def parse_int_column_fused(css, offset, length, width: int = 11,
-                           block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
                            window_rows: int = 0, window_bytes: int = 0,
-                           interpret: bool = True) -> typeconv_mod.Parsed:
+                           *, interpret: bool) -> typeconv_mod.Parsed:
     """Fused equivalent of ``parse_int_column`` (bit-identical, no XLA
-    gather); windowed per-block CSS DMA unless ``window_rows=WHOLE_CSS``."""
+    gather): per-block CSS window DMA, per-row windows as the fallback."""
     return _fused_column(
-        functools.partial(numparse.parse_int_fields_fused, width=width),
         functools.partial(numparse.parse_int_fields_windowed, width=width),
-        css, offset, length, width, block_rows, window_rows, window_bytes,
+        functools.partial(numparse.parse_int_fields_per_row, width=width),
+        css, offset, length, width, jnp.int32, window_rows, window_bytes,
         interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_FUSED_STATICS)
 def parse_float_column_fused(css, offset, length, width: int = 24,
-                             block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
                              window_rows: int = 0, window_bytes: int = 0,
-                             interpret: bool = True) -> typeconv_mod.Parsed:
+                             *, interpret: bool) -> typeconv_mod.Parsed:
     """Fused equivalent of ``parse_float_column`` (bit-identical, no XLA
-    gather); windowed per-block CSS DMA unless ``window_rows=WHOLE_CSS``."""
+    gather): per-block CSS window DMA, per-row windows as the fallback."""
     return _fused_column(
-        functools.partial(numparse.parse_float_fields_fused, width=width),
         functools.partial(numparse.parse_float_fields_windowed, width=width),
-        css, offset, length, width, block_rows, window_rows, window_bytes,
+        functools.partial(numparse.parse_float_fields_per_row, width=width),
+        css, offset, length, width, jnp.float32, window_rows, window_bytes,
         interpret)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_rows", "window_rows",
-                                    "window_bytes", "interpret"))
+                   static_argnames=("window_rows", "window_bytes", "interpret"))
 def parse_date_column_fused(css, offset, length,
-                            block_rows: int = numparse.DEFAULT_BLOCK_ROWS,
                             window_rows: int = 0, window_bytes: int = 0,
-                            interpret: bool = True) -> typeconv_mod.Parsed:
+                            *, interpret: bool) -> typeconv_mod.Parsed:
     """Fused equivalent of ``parse_date_column`` (bit-identical, no XLA
-    gather); windowed per-block CSS DMA unless ``window_rows=WHOLE_CSS``."""
-    return _fused_column(numparse.parse_date_fields_fused,
-                         numparse.parse_date_fields_windowed,
-                         css, offset, length, numparse.DATE_WIDTH, block_rows,
+    gather): per-block CSS window DMA, per-row windows as the fallback."""
+    return _fused_column(numparse.parse_date_fields_windowed,
+                         numparse.parse_date_fields_per_row,
+                         css, offset, length, numparse.DATE_WIDTH, jnp.int32,
                          window_rows, window_bytes, interpret)

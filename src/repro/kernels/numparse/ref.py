@@ -35,21 +35,3 @@ def parse_date_fields(field_bytes, lengths):
     css, offsets, _ = _as_column(field_bytes)
     parsed = typeconv.parse_date(css, offsets, lengths)
     return parsed.value, parsed.valid
-
-
-# The fused kernels' contract IS the typeconv contract (css, offset, length),
-# so their oracles are the typeconv parsers verbatim.
-
-def parse_int_fields_fused(css, offsets, lengths, width):
-    parsed = typeconv.parse_int(css, offsets, lengths, width=width)
-    return parsed.value, parsed.valid
-
-
-def parse_float_fields_fused(css, offsets, lengths, width):
-    parsed = typeconv.parse_float(css, offsets, lengths, width=width)
-    return parsed.value, parsed.valid
-
-
-def parse_date_fields_fused(css, offsets, lengths):
-    parsed = typeconv.parse_date(css, offsets, lengths)
-    return parsed.value, parsed.valid

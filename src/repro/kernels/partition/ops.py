@@ -30,7 +30,7 @@ def partition_tags(
     *,
     block_tags: int = kernels.DEFAULT_BLOCK_TAGS,
     block_rows: int = kernels.DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Partitioned:
     """Kernel-backed equivalent of ``core.partition.partition_scatter2``."""
     n = col_tag.shape[0]

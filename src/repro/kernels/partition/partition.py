@@ -12,10 +12,15 @@ global pass), rank every tag inside its block, and emit each symbol's
 
     rel[i] = (# earlier symbols with the same column tag)
 
-All per-step work is a handful of wide vector ops on a 3D one-hot
-(``(block_rows, block_tags, n_cols+1)``), never a per-column loop, so cost
-is independent of schema width up to VMEM.  The column axis is tiny
-(≤ a few dozen) and rides the trailing one-hot dimension.
+Mosaic has no ``cumsum``, so both exclusive prefixes are triangular
+matmuls on the MXU, one column at a time: for the column's 0/1 mask ``m``
+of a ``(block_rows, block_tags)`` step, ``m @ U`` (``U`` strictly upper
+triangular) counts the same-column tags earlier in each block and the
+lane sum of ``L @ m`` (``L`` strictly lower triangular) those in earlier
+blocks of the step.  The masks are exact in bfloat16 and the products
+accumulate in float32, whose integers are exact far beyond one step's
+``block_rows * block_tags`` tags; the running carry across steps stays
+int32.  The column axis is tiny (≤ a few dozen) and is unrolled.
 
 What stays in XLA glue (``ops.partition_tags``): turning the carry's final
 value into global column starts (an ``n_cols+1``-sized exclusive cumsum),
@@ -42,14 +47,6 @@ DEFAULT_BLOCK_TAGS = 256
 DEFAULT_BLOCK_ROWS = 64
 
 
-def _onehot(tags, n_parts):
-    """``(BR, BN, n_parts) int32`` column one-hot — one dense 3D op, not a
-    per-column loop, so the work stays a handful of wide vector ops however
-    many columns the schema has (scatter2's structure, VMEM-resident)."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, tags.shape + (n_parts,), 2)
-    return (tags[:, :, None] == cols).astype(jnp.int32)
-
-
 def _make_partition_kernel(n_parts: int, block_rows: int, block_tags: int):
     def kernel(tags_ref, rel_ref, count_ref, carry_ref):
         # carry_ref (1, n_parts) VMEM scratch: per-column count of all tags
@@ -59,21 +56,36 @@ def _make_partition_kernel(n_parts: int, block_rows: int, block_tags: int):
         def _():
             carry_ref[...] = jnp.zeros((1, n_parts), jnp.int32)
 
-        tags = tags_ref[...]                          # (BR, BN)
-        onehot = _onehot(tags, n_parts)               # (BR, BN, C+1)
-        block_hist = jnp.sum(onehot, axis=1)          # (BR, C+1)
-        # Exclusive running count per column at each block: earlier grid
-        # steps (carry) + earlier blocks within this step.
-        block_excl = (jnp.cumsum(block_hist, axis=0) - block_hist
-                      + carry_ref[...])               # (BR, C+1)
-        # Stable intra-block rank: exclusive prefix along the tag axis,
-        # selected at each tag's own column.
-        ranks = jnp.cumsum(onehot, axis=1) - onehot   # (BR, BN, C+1)
-        own_rank = jnp.sum(ranks * onehot, axis=2)    # (BR, BN)
-        own_excl = jnp.einsum("rnc,rc->rn", onehot, block_excl)
-        rel_ref[...] = own_excl + own_rank
+        def tri(n, strict_upper):
+            i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+            return ((i < j) if strict_upper else (j < i)).astype(jnp.bfloat16)
 
-        carry_ref[...] += jnp.sum(block_hist, axis=0, keepdims=True)
+        upper = tri(block_tags, True)     # U[j, n] = j < n
+        lower = tri(block_rows, False)    # L[r, q] = q < r
+        dot = lambda a, b: jnp.dot(a, b, preferred_element_type=jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_parts), 1)
+
+        tags = tags_ref[...]                          # (BR, BN)
+        carry = carry_ref[...]                        # (1, C+1)
+        rel = jnp.zeros(tags.shape, jnp.int32)
+        step_count = jnp.zeros((1, n_parts), jnp.int32)
+        for p in range(n_parts):
+            hit = tags == p
+            m = hit.astype(jnp.bfloat16)
+            # Stable rank: same-column tags earlier in this block, plus
+            # those in earlier blocks of this step, plus earlier steps.
+            in_block = dot(m, upper)                              # (BR, BN)
+            earlier_blocks = jnp.sum(dot(lower, m), axis=1, keepdims=True)
+            rank = (in_block + earlier_blocks).astype(jnp.int32)
+            rel = jnp.where(hit, rank + carry[:, p:p + 1], rel)
+            total = jnp.sum(jnp.sum(m.astype(jnp.float32), axis=1,
+                                    keepdims=True), axis=0, keepdims=True)
+            step_count = jnp.where(lane == p, total.astype(jnp.int32),
+                                   step_count)
+        rel_ref[...] = rel
+
+        carry_ref[...] = carry + step_count
         count_ref[...] = carry_ref[...]               # last step's write wins
 
     return kernel
@@ -84,7 +96,7 @@ def partition_blocks(
     n_cols: int,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """``(NB, BN) int32`` blocked tags → column-relative destinations
     ``(NB, BN) int32`` plus total per-column counts ``(n_cols+1,) int32``

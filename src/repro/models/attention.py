@@ -148,10 +148,11 @@ def multihead_attention(q, k, v, *, causal=True, window=None, impl="dense",
     if impl == "chunked":
         return _chunked_attn(q, k, v, causal, window, chunk_q, chunk_kv)
     if impl == "pallas":
+        from repro.core.backends import interpret_kernels
         from repro.kernels.flashattn import flash_attention
         o = flash_attention(
             jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1), jnp.moveaxis(v, 2, 1),
-            causal=causal, window=window,
+            causal=causal, window=window, interpret=interpret_kernels(),
         )
         return jnp.moveaxis(o, 1, 2)
     return _dense_attn(q, k, v, causal, window, q_offset)

@@ -23,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models import layers as L
 
@@ -286,7 +286,7 @@ def moe_a2a(params, x, cfg, sh, mesh):
 
     y, aux = shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=(p_x, P()), check_rep=False,
+        out_specs=(p_x, P()), check_vma=False,
     )(params, x)
     return y, aux
 

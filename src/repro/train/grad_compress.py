@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _quantize(g):
@@ -43,7 +43,7 @@ def compressed_psum_grads(grads, sh, mesh):
 
         spec = P()  # replicated view per dp rank
         return shard_map(
-            body, mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False
+            body, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
         )(g)
 
     return jax.tree.map(one, grads)

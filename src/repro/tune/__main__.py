@@ -28,6 +28,7 @@ import sys
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.parse_formats import tuned_parser_config
 from repro.core import ParserConfig, Schema, make_csv_dfa
 from repro.data import synth
@@ -77,6 +78,7 @@ def stream_sources(name: str, records: int, n: int):
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="python -m repro.tune", description=__doc__.splitlines()[0])
     ap.add_argument("--workloads", default=",".join(ALL_WORKLOADS),

@@ -78,6 +78,7 @@ def tune_key(cfg, device=None) -> Tuple[str, Dict[str, Any]]:
     excluded by construction: two configs differing only in tuned knobs
     share one entry.
     """
+    from repro.core import backends as backends_mod
     from repro.core import stages as stages_mod
 
     if device is None:
@@ -90,7 +91,7 @@ def tune_key(cfg, device=None) -> Tuple[str, Dict[str, Any]]:
         "backend": cfg.backend,
         "device_kind": str(device.device_kind),
         "platform": str(device.platform),
-        "interpret": bool(getattr(cfg, "interpret", True)),
+        "interpret": backends_mod.interpret_kernels(),
         "dfa": dfa_digest,
         "schema": [[c.dtype, bool(c.selected)] for c in cfg.schema.columns],
         "tagging": cfg.tagging,

@@ -47,12 +47,26 @@ class Knob:
         return value in self.candidates(backend)
 
 
-def _pallas_only(vals):
-    return lambda be: vals if be.name == "pallas" else ()
+def _on_tpu() -> bool:
+    from repro.core.backends import interpret_kernels
+
+    return not interpret_kernels()
+
+
+def _pallas_only(vals, tpu_vals=None):
+    """Candidates for the pallas backend; ``tpu_vals`` narrows them where
+    the kernels compile for a TPU (block shapes Mosaic tiles, VMEM that
+    holds the step)."""
+    def cands(be):
+        if be.name != "pallas":
+            return ()
+        return tpu_vals if tpu_vals is not None and _on_tpu() else vals
+    return cands
 
 
 def _has_fused_executor(vals):
-    return lambda be: vals if be.execute is not None else ()
+    # The megakernel has no Mosaic lowering yet: never offered on a TPU.
+    return lambda be: vals if be.execute is not None and not _on_tpu() else ()
 
 
 #: The search space, in sweep order: cheap/high-leverage knobs first so a
@@ -64,7 +78,7 @@ SPACE: Tuple[Knob, ...] = (
         lambda be: be.partition_impls,
         "§3.3 stable-partition implementation (jnp radix variants vs the "
         "Pallas radix kernel).  The hand heuristic — scatter on reference, "
-        "scatter2-under-interpret/kernel-on-hardware on pallas — becomes "
+        "scatter2 off the chip/kernel on a TPU on pallas — becomes "
         "the cold-cache default.",
     ),
     Knob(
@@ -84,19 +98,19 @@ SPACE: Tuple[Knob, ...] = (
     ),
     Knob(
         "block_chunks", "scan", 0,
-        _pallas_only((64, 128, 256, 512)),
+        _pallas_only((64, 128, 256, 512), tpu_vals=(128, 256, 512)),
         "Chunks per Pallas grid step in the §3.1 DFA-scan kernels "
         "(launch geometry; 0 = kernel default).",
     ),
     Knob(
         "window_rows", "typeconv", 0,
-        _pallas_only((0, 128, 256, 1024, -1)),
+        _pallas_only((0, 128, 256, 1024), tpu_vals=(0, 128, 256)),
         "Rows per CSS-window DMA block in the fused numparse kernels "
-        "(0 = kernel default, -1 = whole-CSS-in-VMEM).",
+        "(0 = kernel default).",
     ),
     Knob(
         "max_window_bytes", "typeconv", 0,
-        _pallas_only((0, 4096, 16384)),
+        _pallas_only((0, 4096, 16384), tpu_vals=(0, 4096)),
         "Static CSS window tile bytes (0 = auto-size from window_rows and "
         "the dtype width).",
     ),
@@ -108,7 +122,7 @@ SPACE: Tuple[Knob, ...] = (
     ),
     Knob(
         "partition_block_tags", "partition", 0,
-        _pallas_only((0, 1024, 4096)),
+        _pallas_only((0, 1024, 4096), tpu_vals=(0, 512)),
         "Tags per block in the Pallas radix-partition kernel "
         "(partition_impl='kernel' only; 0 = kernel default).",
     ),
@@ -116,8 +130,7 @@ SPACE: Tuple[Knob, ...] = (
         "fused_max_bytes", "pipeline", 0,
         _has_fused_executor((0, 1 << 20, 16 << 20)),
         "Static byte cap above which a fused plan falls back to the "
-        "staged tier (0 = backend default, 4 MiB on pallas) — on real "
-        "hardware the VMEM ceiling, measurable only there.",
+        "staged tier (0 = backend default, 4 MiB on pallas).",
     ),
 )
 

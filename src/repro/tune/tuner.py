@@ -141,16 +141,16 @@ def tune_parse(
     best_seconds = float("inf")
 
     def build(cand: Dict[str, Any], count: bool = True):
-        """Compile + identity-gate one candidate; None if rejected."""
+        """Compile + identity-gate one candidate; None if rejected.
+
+        Build, lowering and compile errors propagate: the space only
+        offers candidates that compile on this platform, so a failure is
+        a fault to report, never a candidate that quietly drops out."""
         nonlocal evaluated
         if count:
             evaluated += 1
-        try:
-            p = Parser(space_mod.apply_assignment(cfg, cand))
-            out = jax.block_until_ready(p.parse_chunks(chunks))
-        except Exception as e:  # a candidate that won't build can't win
-            trials.append(Trial(dict(cand), rejected=f"error: {e!r}"))
-            return None
+        p = Parser(space_mod.apply_assignment(cfg, cand))
+        out = jax.block_until_ready(p.parse_chunks(chunks))
         if not measure_mod.signatures_equal(
                 measure_mod.parse_signature(out), ref_sig):
             trials.append(Trial(

@@ -5,6 +5,7 @@ import pytest
 
 from jaxpr_utils import gathers_outside_pallas as _gathers_outside_pallas
 from repro.core import make_csv_dfa, make_log_dfa, make_simple_dfa
+from repro.core.backends import interpret_kernels
 
 # ---------------------------------------------------------------------------
 # dfa_scan
@@ -25,7 +26,8 @@ def test_dfa_scan_chunk_vectors(rng, dfa_name, n_chunks, chunk_bytes, block):
         alphabet[rng.integers(0, len(alphabet), size=n_chunks * chunk_bytes)]
         .reshape(n_chunks, chunk_bytes)
     )
-    got = ops.chunk_vectors(chunks, dfa, block_chunks=block)
+    got = ops.chunk_vectors(chunks, dfa, block_chunks=block,
+                            interpret=interpret_kernels())
     want = ref.chunk_vectors(chunks, dfa)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -39,7 +41,8 @@ def test_dfa_scan_replay(rng, dfa_name):
         alphabet[rng.integers(0, len(alphabet), size=256 * 48)].reshape(256, 48)
     )
     starts = jnp.asarray(rng.integers(0, dfa.n_states, size=256), jnp.int32)
-    c_k, e_k = ops.replay(chunks, starts, dfa, block_chunks=64)
+    c_k, e_k = ops.replay(chunks, starts, dfa, block_chunks=64,
+                          interpret=interpret_kernels())
     c_r, e_r = ref.replay(chunks, starts, dfa)
     np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_r))
     np.testing.assert_array_equal(np.asarray(e_k), np.asarray(e_r))
@@ -53,7 +56,7 @@ def test_dfa_scan_end_to_end_matches_pipeline(rng):
     chunks = jnp.asarray(
         alphabet[rng.integers(0, len(alphabet), size=512 * 64)].reshape(512, 64)
     )
-    cls_k, _ = ops.parse_classes(chunks, dfa)
+    cls_k, _ = ops.parse_classes(chunks, dfa, interpret=interpret_kernels())
     cls_j, _, _ = transition_pipeline(chunks, dfa)
     np.testing.assert_array_equal(np.asarray(cls_k), np.asarray(cls_j))
 
@@ -85,7 +88,8 @@ def test_numparse_int(rng, width, rows, block):
         bs = s.encode()[:width]
         byts[i, : len(bs)] = np.frombuffer(bs, np.uint8)
         lens[i] = len(bs)
-    got_v, got_ok = k_ops.parse_int_fields(jnp.asarray(byts), jnp.asarray(lens), block_rows=block)
+    got_v, got_ok = k_ops.parse_int_fields(jnp.asarray(byts), jnp.asarray(lens), block_rows=block,
+                                           interpret=interpret_kernels())
     want_v, want_ok = k_ref.parse_int_fields(jnp.asarray(byts), jnp.asarray(lens))
     np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))
     ok = np.asarray(got_ok)
@@ -115,7 +119,8 @@ def test_numparse_int_overflow(rng):
             "9999999999", "0000000001", "00000000000042", "12345678901"]
     strs += [str(int(v)) for v in rng.integers(2**31 - 100, 2**31 + 100, size=24)]
     byts, lens = _pack_rows(strs, 16)
-    got_v, got_ok = k_ops.parse_int_fields(byts, lens, block_rows=len(strs))
+    got_v, got_ok = k_ops.parse_int_fields(byts, lens, block_rows=len(strs),
+                                           interpret=interpret_kernels())
     want_v, want_ok = k_ref.parse_int_fields(byts, lens)
     np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))
     ok = np.asarray(got_ok)
@@ -145,7 +150,8 @@ def test_numparse_float(rng, width, rows, block):
         else:
             strs.append(str(int(rng.integers(-10**9, 10**9))))
     byts, lens = _pack_rows(strs, width)
-    got_v, got_ok = k_ops.parse_float_fields(byts, lens, block_rows=block)
+    got_v, got_ok = k_ops.parse_float_fields(byts, lens, block_rows=block,
+                                             interpret=interpret_kernels())
     want_v, want_ok = k_ref.parse_float_fields(byts, lens)
     np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))
     ok = np.asarray(got_ok)
@@ -172,7 +178,8 @@ def test_numparse_date(rng, rows, block):
             strs.append(rng.choice(["", "junk", "2024-1-01", "2024/01/01",
                                     "2024-01-01x00:00:00", "2024-00-10"]))
     byts, lens = _pack_rows(strs, 19)
-    got_v, got_ok = k_ops.parse_date_fields(byts, lens, block_rows=block)
+    got_v, got_ok = k_ops.parse_date_fields(byts, lens, block_rows=block,
+                                            interpret=interpret_kernels())
     want_v, want_ok = k_ref.parse_date_fields(byts, lens)
     np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))
     np.testing.assert_array_equal(np.asarray(got_v)[np.asarray(got_ok)],
@@ -229,9 +236,9 @@ def test_numparse_fused_matches_unfused_and_typeconv(rows, block):
     ]
     for strs, fused, unfused, oracle in cases:
         css, offs, lens = _pack_css(strs)
-        got = fused(css, offs, lens, block_rows=block)
+        got = fused(css, offs, lens, window_rows=block, interpret=interpret_kernels())
         # vs the unfused kernel: bit-identical on everything (shared arith).
-        want = unfused(css, offs, lens, block_rows=block)
+        want = unfused(css, offs, lens, block_rows=block, interpret=interpret_kernels())
         for f in ("value", "valid", "empty"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(got, f)), np.asarray(getattr(want, f)),
@@ -256,8 +263,8 @@ def test_numparse_fused_field_at_css_end():
     from repro.kernels.numparse import ops as k_ops
     strs = ["123", "-45", "678"]
     css, offs, lens = _pack_css(strs)
-    got = k_ops.parse_int_column_fused(css, offs, lens)
-    want = k_ops.parse_int_column(css, offs, lens)
+    got = k_ops.parse_int_column_fused(css, offs, lens, interpret=interpret_kernels())
+    want = k_ops.parse_int_column(css, offs, lens, interpret=interpret_kernels())
     for f in ("value", "valid", "empty"):
         np.testing.assert_array_equal(np.asarray(getattr(got, f)),
                                       np.asarray(getattr(want, f)))
@@ -277,8 +284,8 @@ def test_numparse_fused_issues_no_xla_gather():
     ln = jnp.zeros(64, jnp.int32)
     schema = Schema.of(("i", "int32"), ("f", "float32"), ("d", "date"))
 
-    # default config = windowed fused path; window_rows=-1 = whole-CSS fused
-    for window_rows in (0, -1):
+    # default config and an explicit window geometry, both windowed
+    for window_rows in (0, 128):
         fused_cfg = ParserConfig(dfa=make_csv_dfa(), schema=schema,
                                  max_records=64, backend="pallas",
                                  fuse_typeconv=True, window_rows=window_rows)
@@ -313,7 +320,8 @@ def test_flashattn_vs_ref(rng, dtype, b, hq, hkv, sq, skv, d, causal, window):
     q = jnp.asarray(rng.normal(size=(b, hq, sq, d)), dtype)
     k = jnp.asarray(rng.normal(size=(b, hkv, skv, d)), dtype)
     v = jnp.asarray(rng.normal(size=(b, hkv, skv, d)), dtype)
-    got = f_ops.flash_attention(q, k, v, causal=causal, window=window, block_q=128, block_kv=128)
+    got = f_ops.flash_attention(q, k, v, causal=causal, window=window, block_q=128, block_kv=128,
+                                interpret=interpret_kernels())
     want = f_ref.flash_attention(q, k, v, causal=causal, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
@@ -329,7 +337,8 @@ def test_flashattn_block_shape_sweep(rng):
     v = jnp.asarray(rng.normal(size=(1, 2, 256, 64)), jnp.float32)
     want = f_ref.flash_attention(q, k, v, causal=True)
     for bq, bk in [(64, 64), (128, 64), (64, 128), (256, 256)]:
-        got = f_ops.flash_attention(q, k, v, causal=True, block_q=bq, block_kv=bk)
+        got = f_ops.flash_attention(q, k, v, causal=True, block_q=bq, block_kv=bk,
+                                    interpret=interpret_kernels())
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
@@ -348,7 +357,8 @@ def test_dfa_scan_replay_fused_summaries(rng, dfa_name):
     )
     starts = jnp.zeros((256,), jnp.int32) + dfa.start_state
 
-    cls_k, ends_k, summ = ops.replay_fused(chunks, starts, dfa, block_chunks=64)
+    cls_k, ends_k, summ = ops.replay_fused(chunks, starts, dfa, block_chunks=64,
+                                           interpret=interpret_kernels())
     groups = byte_groups(chunks, dfa)
     cls_r, ends_r, _ = jnp_replay(groups, starts, dfa)
     np.testing.assert_array_equal(np.asarray(cls_k), np.asarray(cls_r))
@@ -374,7 +384,8 @@ def test_dfa_scan_parse_contexts(rng, n_chunks):
         alphabet[rng.integers(0, len(alphabet), size=n_chunks * 32)]
         .reshape(n_chunks, 32)
     )
-    cls_k, ends_k, summ = ops.parse_contexts(chunks, dfa, block_chunks=32)
+    cls_k, ends_k, summ = ops.parse_contexts(chunks, dfa, block_chunks=32,
+                                             interpret=interpret_kernels())
     cls_j, ends_j, _ = transition_pipeline(chunks, dfa)
     np.testing.assert_array_equal(np.asarray(cls_k), np.asarray(cls_j))
     np.testing.assert_array_equal(np.asarray(ends_k), np.asarray(ends_j))

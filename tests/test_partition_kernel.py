@@ -12,6 +12,7 @@ import pytest
 
 from repro.core import Parser, ParserConfig, Schema, make_csv_dfa
 from repro.core import partition as partition_mod
+from repro.core.backends import interpret_kernels
 from repro.kernels.partition import ops as part_ops
 from repro.kernels.partition import partition as part_kernels
 from repro.kernels.partition import ref as part_ref
@@ -27,7 +28,7 @@ def test_kernel_matches_all_jnp_impls(n, c):
     # local generator: the session `rng` fixture's stream is order-sensitive
     rng = np.random.default_rng(n * 31 + c)
     tags = jnp.asarray(rng.integers(0, c + 1, size=n), jnp.int32)  # incl. sentinel
-    got = part_ops.partition_tags(tags, c)
+    got = part_ops.partition_tags(tags, c, interpret=interpret_kernels())
     oracle = part_ref.partition_tags(tags, c)
     for name, impl in {**partition_mod.PARTITION_IMPLS, "ref": lambda t, k: oracle}.items():
         want = impl(tags, c)
@@ -48,7 +49,8 @@ def test_kernel_nondefault_blocks():
     tags = jnp.asarray(rng.integers(0, c + 1, size=n), jnp.int32)
     want = partition_mod.partition_argsort(tags, c)
     for bn, br in [(64, 2), (1000, 1), (2048, 4)]:
-        got = part_ops.partition_tags(tags, c, block_tags=bn, block_rows=br)
+        got = part_ops.partition_tags(tags, c, block_tags=bn, block_rows=br,
+                                      interpret=interpret_kernels())
         np.testing.assert_array_equal(
             np.asarray(got.perm), np.asarray(want.perm), err_msg=f"bn={bn},br={br}")
         np.testing.assert_array_equal(
@@ -62,7 +64,7 @@ def test_kernel_degenerate_streams():
                     np.full(300, c, np.int32),          # all sentinel (dropped)
                     np.arange(300, dtype=np.int32) % (c + 1)):
         tags = jnp.asarray(tags_np)
-        got = part_ops.partition_tags(tags, c)
+        got = part_ops.partition_tags(tags, c, interpret=interpret_kernels())
         want = partition_mod.partition_scatter(tags, c)
         np.testing.assert_array_equal(np.asarray(got.perm), np.asarray(want.perm))
         np.testing.assert_array_equal(
@@ -77,7 +79,7 @@ def test_partition_blocks_counts_and_rel():
     tags_np = rng.integers(0, c + 1, size=n).astype(np.int32)
     tags = jnp.asarray(tags_np)
     rel, count = part_kernels.partition_blocks(tags.reshape(n // bn, bn), c,
-                                               block_rows=4)
+                                               block_rows=4, interpret=interpret_kernels())
     np.testing.assert_array_equal(
         np.asarray(count), np.asarray(partition_mod.column_histogram(tags, c)))
     want_rel = np.empty(n, np.int32)
@@ -137,7 +139,8 @@ def test_kernel_partition_stable_property():
     def check(seed, n, c):
         rng = np.random.default_rng(seed)
         tags = jnp.asarray(rng.integers(0, c + 1, size=n), jnp.int32)
-        got = part_ops.partition_tags(tags, c, block_tags=128, block_rows=4)
+        got = part_ops.partition_tags(tags, c, block_tags=128, block_rows=4,
+                                      interpret=interpret_kernels())
         perm = np.asarray(got.perm)
         tags_np = np.asarray(tags)
         # permutation correctness

@@ -105,3 +105,26 @@ def test_chunked_ids_match_flat(seed, c, k):
     np.testing.assert_array_equal(np.asarray(two.record_id), np.asarray(flat.record_id))
     np.testing.assert_array_equal(np.asarray(two.column_id), np.asarray(flat.column_id))
     assert int(two.n_records) == int(flat.n_records)
+
+
+@pytest.mark.parametrize("shape,axis", [
+    ((1,), 0), ((128,), 0), ((129,), 0), ((2000,), 0),    # around BLOCK
+    ((37, 300), 1), ((300, 7), 0), ((3, 17000), 1),   # two recursion levels
+])
+def test_blocked_scans_match_jnp(shape, axis):
+    """``core.scans`` (blocked associative scans) equal ``jnp.cumsum`` /
+    ``lax.cummax`` bit for bit, and the blocked composite scan equals the
+    flat ``lax.associative_scan`` of ``transition.compose``."""
+    from repro.core import scans
+
+    rng = np.random.default_rng(sum(shape))
+    x = jnp.asarray(rng.integers(-50, 50, size=shape), jnp.int32)
+    vecs = jnp.asarray(rng.integers(0, S, size=(shape[0], S)), jnp.int32)
+    got = jax.jit(lambda x, v: (
+        scans.cumsum(x, axis), scans.cummax(x, axis),
+        scans.scan(tr.compose, v, tr.identity_vector(S))))(x, vecs)
+    want = jax.jit(lambda x, v: (
+        jnp.cumsum(x, axis=axis), jax.lax.cummax(x, axis=axis),
+        jax.lax.associative_scan(tr.compose, v, axis=0)))(x, vecs)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

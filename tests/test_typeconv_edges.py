@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from repro.core import typeconv
+from repro.core.backends import interpret_kernels
 from repro.kernels.numparse import ops as k_ops
 
 INT32_MAX = 2**31 - 1
@@ -72,7 +73,8 @@ def test_parse_int_overflow_gather_and_kernel():
     css, offs, lens = _column(strs)
     width = int(lens.max())
     ref = typeconv.parse_int(css, offs, lens, width=width)
-    pal = k_ops.parse_int_column(css, offs, lens, width=width)
+    pal = k_ops.parse_int_column(css, offs, lens, width=width,
+                                 interpret=interpret_kernels())
     want_valid = np.asarray([v for _, v in INT_CASES])
     np.testing.assert_array_equal(np.asarray(ref.valid), want_valid)
     np.testing.assert_array_equal(np.asarray(pal.valid), want_valid)
@@ -101,7 +103,8 @@ def test_int_paths_reconciled_on_long_digit_runs():
     fstart, fid = _segmented_inputs(strs)
     gat = typeconv.parse_int(css, offs, lens, width=width)
     seg = typeconv.parse_int_segmented(css, fstart, fid, len(strs))
-    pal = k_ops.parse_int_column(css, offs, lens, width=width)
+    pal = k_ops.parse_int_column(css, offs, lens, width=width,
+                                 interpret=interpret_kernels())
     np.testing.assert_array_equal(np.asarray(gat.valid), np.asarray(seg.valid))
     np.testing.assert_array_equal(np.asarray(gat.valid), np.asarray(pal.valid))
     want_valid = np.asarray([True, True, True, True, True])
@@ -152,7 +155,7 @@ def test_parse_date_semantics_both_backends():
     strs = [s for s, _ in DATE_CASES]
     css, offs, lens = _column(strs)
     ref = typeconv.parse_date(css, offs, lens)
-    pal = k_ops.parse_date_column(css, offs, lens)
+    pal = k_ops.parse_date_column(css, offs, lens, interpret=interpret_kernels())
     want_valid = np.asarray([v for _, v in DATE_CASES])
     np.testing.assert_array_equal(np.asarray(ref.valid), want_valid,
                                   err_msg=str(strs))
@@ -202,7 +205,8 @@ def test_parse_float_boundaries_both_backends():
     strs = [s for s, _, _ in FLOAT_CASES]
     css, offs, lens = _column(strs)
     ref = typeconv.parse_float(css, offs, lens, width=24)
-    pal = k_ops.parse_float_column(css, offs, lens, width=24)
+    pal = k_ops.parse_float_column(css, offs, lens, width=24,
+                                   interpret=interpret_kernels())
     want_valid = np.asarray([v for _, v, _ in FLOAT_CASES])
     np.testing.assert_array_equal(np.asarray(ref.valid), want_valid,
                                   err_msg=str(strs))
@@ -225,7 +229,8 @@ def test_parse_float_inf_overflow_matches_python():
     strs = ["3e38", "4e38", "1e40", "-4e38"]
     css, offs, lens = _column(strs)
     ref = typeconv.parse_float(css, offs, lens, width=24)
-    pal = k_ops.parse_float_column(css, offs, lens, width=24)
+    pal = k_ops.parse_float_column(css, offs, lens, width=24,
+                                   interpret=interpret_kernels())
     np.testing.assert_array_equal(np.asarray(ref.value), np.asarray(pal.value))
     with np.errstate(over="ignore"):  # the float32 cast overflows by design
         want = [np.float32(float(s)) for s in strs]

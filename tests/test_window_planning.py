@@ -8,6 +8,9 @@ starts, tight windows, monotone/fits detection), the degenerate shapes
 multi-tile mega-field fallback, non-monotone offsets), and the acceptance
 bar that the windowed path still issues no XLA gather outside pallas_call.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +18,7 @@ import pytest
 
 from jaxpr_utils import gathers_outside_pallas as _gathers_outside_pallas
 from repro.core import typeconv
+from repro.core.backends import interpret_kernels
 from repro.kernels.numparse import numparse
 from repro.kernels.numparse import ops as k_ops
 
@@ -39,6 +43,13 @@ def _plan(offs, lens, br, width, wt, css_len):
         jnp.asarray(offs, jnp.int32), jnp.asarray(lens, jnp.int32),
         rows_per_block=br, width=width, window_bytes=wt, css_len=css_len,
     )
+
+
+def _per_row(fn, css, offs, lens, **kw):
+    """A per-row-window kernel's result as a ``typeconv.Parsed``."""
+    val, ok = fn(css, offs, lens, interpret=interpret_kernels(), **kw)
+    empty = lens == 0
+    return typeconv.Parsed(val, ok & ~empty, empty)
 
 
 def _assert_parsed_equal(got, want, msg=""):
@@ -147,13 +158,9 @@ def test_plan_leading_empty_does_not_drag_window_to_css_start():
 
 
 def test_per_row_window_fallback_handles_arbitrary_offsets():
-    """The large-CSS fallback (per-row windows) parses correctly with
-    non-monotone offsets and mega-fields — the shapes the block-window
-    invariant cannot cover."""
-    import functools
-
-    from repro.kernels.numparse import numparse
-
+    """The windowed path's fallback (per-row windows) parses correctly
+    with non-monotone offsets and mega-fields — the shapes the
+    block-window invariant cannot cover."""
     strs = [str(i * 31) for i in range(64)] + ["8" * 900]
     css, offs, lens = _pack_css(strs)
     # shuffle the index: rows no longer sorted by offset
@@ -161,10 +168,9 @@ def test_per_row_window_fallback_handles_arbitrary_offsets():
     offs = jnp.asarray(np.asarray(offs)[perm])
     lens = jnp.asarray(np.asarray(lens)[perm])
     got = k_ops._fused_column(
-        functools.partial(numparse.parse_int_fields_fused, width=11),
         functools.partial(numparse.parse_int_fields_windowed, width=11),
-        css, offs, lens, 11, numparse.DEFAULT_BLOCK_ROWS, 0, 0, True,
-        wholecss_max=0,  # force the per-row tier even for this small CSS
+        functools.partial(numparse.parse_int_fields_per_row, width=11),
+        css, offs, lens, 11, jnp.int32, 0, 0, interpret_kernels(),
     )
     ref = typeconv.parse_int(css, offs, lens, width=11)
     np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(ref.valid))
@@ -173,17 +179,44 @@ def test_per_row_window_fallback_handles_arbitrary_offsets():
                                   np.asarray(ref.value)[ok])
 
 
+def test_fused_column_vmap_keeps_each_lanes_choice():
+    """Under ``vmap`` each lane keeps its own windowed/per-row choice: a
+    batch mixing a mega-field lane with a well-formed lane equals the
+    lanes parsed alone, and a batch where every lane fits stays a branch
+    (``cond``) instead of a select that would run the per-row kernel."""
+    good = [str(i * 7) for i in range(60)] + ["12"] * 4
+    bad = [str(i) for i in range(30)] + ["7" * 3000] + [str(-i) for i in range(33)]
+    lanes = [_pack_css(s) for s in (good, bad)]
+    n = max(int(c.shape[0]) for c, _, _ in lanes)
+    css = jnp.stack([jnp.pad(c, (0, n - c.shape[0])) for c, _, _ in lanes])
+    offs = jnp.stack([o for _, o, _ in lanes])
+    lens = jnp.stack([ln for _, _, ln in lanes])
+    fn = lambda c, o, ln: k_ops.parse_int_column_fused(
+        c, o, ln, window_rows=16, window_bytes=256,
+        interpret=interpret_kernels())
+    got = jax.vmap(fn)(css, offs, lens)
+    for i in range(2):
+        want = fn(css[i], offs[i], lens[i])
+        _assert_parsed_equal(jax.tree.map(lambda x: x[i], got), want,
+                             f"lane {i}")
+    ref = typeconv.parse_int(css[1], offs[1], lens[1], width=11)
+    np.testing.assert_array_equal(np.asarray(got.valid[1]),
+                                  np.asarray(ref.valid))
+    jx = jax.make_jaxpr(jax.vmap(fn))(css, offs, lens)
+    assert re.search(r"\bcond\[", str(jx)), "batched fallback lost its branch"
+
+
 def test_auto_window_bytes_geometry():
     wt = k_ops.auto_window_bytes(512, 11)
     assert wt % ALIGN == 0
     assert wt >= 512 * 12 + 11  # every ≤11-byte field + terminator fits
     # explicit sizes are rounded up to alignment and floored sanely
-    br, wt2 = k_ops._resolve_window(16, 100, 512, 11, 1000)
+    br, wt2 = k_ops._resolve_window(16, 100, 11, 1000)
     assert br == 16 and wt2 % ALIGN == 0 and wt2 >= 11 + ALIGN
 
 
 # ---------------------------------------------------------------------------
-# windowed kernels vs whole-CSS fused vs typeconv
+# windowed kernels vs per-row windows vs typeconv
 # ---------------------------------------------------------------------------
 
 def _mixed_cases(rng, rows):
@@ -205,19 +238,26 @@ def _mixed_cases(rng, rows):
 
 @pytest.mark.parametrize("rows,window_rows", [(500, 32), (512, 512), (33, 8)])
 def test_windowed_matches_wholecss_and_typeconv(rows, window_rows):
+    """The windowed kernels equal the per-row-window kernels (the other
+    CSS-indexing family; the whole-CSS kernels this once compared against
+    are gone) and the typeconv oracle."""
     ints, floats, dates = _mixed_cases(np.random.default_rng(rows), rows)
     cases = [
         (ints, k_ops.parse_int_column_fused,
+         functools.partial(numparse.parse_int_fields_per_row, width=11),
          lambda c, o, l: typeconv.parse_int(c, o, l, width=11)),
         (floats, k_ops.parse_float_column_fused,
+         functools.partial(numparse.parse_float_fields_per_row, width=24),
          lambda c, o, l: typeconv.parse_float(c, o, l, width=24)),
-        (dates, k_ops.parse_date_column_fused, typeconv.parse_date),
+        (dates, k_ops.parse_date_column_fused,
+         numparse.parse_date_fields_per_row, typeconv.parse_date),
     ]
-    for strs, fused, oracle in cases:
+    for strs, fused, per_row, oracle in cases:
         css, offs, lens = _pack_css(strs)
-        got = fused(css, offs, lens, window_rows=window_rows)
-        whole = fused(css, offs, lens, window_rows=k_ops.WHOLE_CSS)
-        _assert_parsed_equal(got, whole, f"{fused.__name__} windowed vs whole")
+        got = fused(css, offs, lens, window_rows=window_rows,
+                    interpret=interpret_kernels())
+        other = _per_row(per_row, css, offs, lens)
+        _assert_parsed_equal(got, other, f"{fused.__name__} windowed vs per-row")
         ref = oracle(css, offs, lens)
         np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(ref.valid))
         np.testing.assert_array_equal(np.asarray(got.empty), np.asarray(ref.empty))
@@ -232,7 +272,8 @@ def test_windowed_field_straddles_two_row_blocks():
     strs = (["%02d" % i for i in range(15)] + ["88887777"]  # long field at
             + ["%02d" % i for i in range(16)])              # a block boundary
     css, offs, lens = _pack_css(strs)
-    got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=16)
+    got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=16,
+                                       interpret=interpret_kernels())
     ref = typeconv.parse_int(css, offs, lens, width=11)
     np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(ref.valid))
     np.testing.assert_array_equal(np.asarray(got.value)[np.asarray(got.valid)],
@@ -243,31 +284,33 @@ def test_windowed_empty_and_all_empty_columns():
     # interleaved empties
     strs = ["" if i % 2 else str(i * 7) for i in range(128)]
     css, offs, lens = _pack_css(strs)
-    got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=16)
-    whole = k_ops.parse_int_column_fused(css, offs, lens,
-                                         window_rows=k_ops.WHOLE_CSS)
-    _assert_parsed_equal(got, whole, "interleaved empties")
+    got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=16,
+                                       interpret=interpret_kernels())
+    other = _per_row(numparse.parse_int_fields_per_row, css, offs, lens,
+                     width=11)
+    _assert_parsed_equal(got, other, "interleaved empties")
     # all-empty column (every offset 0, every length 0)
     css0 = jnp.zeros(1, jnp.uint8)
     z = jnp.zeros(64, jnp.int32)
     for fused in (k_ops.parse_int_column_fused, k_ops.parse_float_column_fused,
                   k_ops.parse_date_column_fused):
-        got = fused(css0, z, z, window_rows=16)
+        got = fused(css0, z, z, window_rows=16, interpret=interpret_kernels())
         assert not np.asarray(got.valid).any()
         assert np.asarray(got.empty).all()
 
 
 def test_windowed_mega_field_falls_back_per_column():
-    """A single multi-tile mega-field flips the column to the whole-CSS
-    kernel at run time — results stay bit-identical to the oracle."""
+    """A single multi-tile mega-field flips the column to per-row windows
+    at run time — results stay bit-identical to the oracle and to the
+    per-row kernel run directly."""
     strs = ([str(i) for i in range(100)] + ["7" * 5000]
             + [str(-i) for i in range(100)])
     css, offs, lens = _pack_css(strs)
     got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=16,
-                                       window_bytes=256)
-    whole = k_ops.parse_int_column_fused(css, offs, lens,
-                                         window_rows=k_ops.WHOLE_CSS)
-    _assert_parsed_equal(got, whole, "mega-field fallback")
+                                       window_bytes=256, interpret=interpret_kernels())
+    other = _per_row(numparse.parse_int_fields_per_row, css, offs, lens,
+                     width=11)
+    _assert_parsed_equal(got, other, "mega-field fallback")
     ref = typeconv.parse_int(css, offs, lens, width=11)
     np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(ref.valid))
     assert not bool(np.asarray(got.valid)[100])  # the mega-field itself: too wide
@@ -278,8 +321,9 @@ def test_windowed_field_at_css_end():
     reading past the buffer."""
     strs = ["123", "-45", "678"]
     css, offs, lens = _pack_css(strs)
-    got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=2)
-    want = k_ops.parse_int_column(css, offs, lens)
+    got = k_ops.parse_int_column_fused(css, offs, lens, window_rows=2,
+                                       interpret=interpret_kernels())
+    want = k_ops.parse_int_column(css, offs, lens, interpret=interpret_kernels())
     _assert_parsed_equal(got, want, "css end")
 
 
@@ -297,7 +341,7 @@ def _taxi_like_rows(n):
     {},                                  # default: windowed, auto tile
     {"window_rows": 8},                  # many tiny windows
     {"max_window_bytes": 384},           # explicit tile
-    {"window_rows": -1},                 # whole-CSS baseline
+    {"window_rows": 128, "max_window_bytes": 4096},  # TPU tuner values
 ])
 def test_parser_window_knobs_match_reference(kw):
     from repro.core import Parser, ParserConfig, Schema, make_csv_dfa
@@ -320,9 +364,10 @@ def test_parser_config_window_knob_validation():
     from repro.core import ParserConfig, Schema, make_csv_dfa
 
     schema = Schema.of(("i", "int32"))
-    with pytest.raises(ValueError, match="window_rows"):
-        ParserConfig(dfa=make_csv_dfa(), schema=schema, max_records=8,
-                     backend="pallas", window_rows=-2)
+    for bad in (-1, -2):
+        with pytest.raises(ValueError, match="window_rows"):
+            ParserConfig(dfa=make_csv_dfa(), schema=schema, max_records=8,
+                         backend="pallas", window_rows=bad)
     with pytest.raises(ValueError, match="max_window_bytes"):
         ParserConfig(dfa=make_csv_dfa(), schema=schema, max_records=8,
                      backend="pallas", max_window_bytes=-1)
@@ -338,7 +383,6 @@ def test_plan_records_typeconv_path():
         get_backend(kw.get("backend", "reference")))
     assert mk().typeconv_path == "reference"
     assert mk(backend="pallas").typeconv_path == "fused-windowed"
-    assert mk(backend="pallas", window_rows=-1).typeconv_path == "fused-wholecss"
     assert mk(backend="pallas", fuse_typeconv=False).typeconv_path == "unfused"
 
 
