@@ -408,24 +408,28 @@ def execute_plan(
         return backend.execute(raw_chunks, plan, cfg, initial_state,
                                stitch=stitch)
 
+    # Each stage runs under one ``stage.<name>`` scope, so the device ops of
+    # a trace name the stage they belong to; glue between two stages goes
+    # under the scope of the stage that consumes it.
     # §3.1/§3.2 — parsing context + fused per-chunk offset summaries (the
     # stitch plugs the cross-device composite prefix into the scan).
-    ctx = determine_contexts(
-        raw_chunks, cfg, backend, initial_state=initial_state,
-        prefix_fn=None if stitch is None else stitch.prefix_fn,
-    )
-    end_state = ctx.end_states[-1]
+    with jax.named_scope("stage.contexts"):
+        ctx = determine_contexts(
+            raw_chunks, cfg, backend, initial_state=initial_state,
+            prefix_fn=None if stitch is None else stitch.prefix_fn,
+        )
 
     # §3.2 — record/column identification from the summaries.  Under a
     # stitch the chunk offsets are globally seeded and materialization runs
     # on shard-local record ids (rec_base restores global ids).
-    if stitch is None:
-        ids = identify_symbols(ctx)
-        rec_for_index = ids.record_id
-    else:
-        offs, rec_base, col_seed, n_total = stitch.offsets_fn(ctx.summaries)
-        ids = identify_symbols(ctx, chunk_offsets=offs)
-        rec_for_index = ids.record_id - rec_base
+    with jax.named_scope("stage.ids"):
+        if stitch is None:
+            ids = identify_symbols(ctx)
+            rec_for_index = ids.record_id
+        else:
+            offs, rec_base, col_seed, n_total = stitch.offsets_fn(ctx.summaries)
+            ids = identify_symbols(ctx, chunk_offsets=offs)
+            rec_for_index = ids.record_id - rec_base
 
     # §3.2/§3.3 — backend-owned materialization: tagging, stable partition,
     # field index, type conversion (one shared stage, one static plan).
@@ -437,22 +441,27 @@ def execute_plan(
     # §4.3 — validation (stitched: local per-record column counts, with the
     # boundary record's count completed by the cross-device column seed,
     # reduced globally by the stitch hook).
-    flat_classes = ctx.classes.reshape(-1)
-    if stitch is None:
-        val = validation_mod.validate(
-            flat_classes, rec_for_index, end_state, ctx.saw_invalid, cfg.dfa,
-            plan.materialize.max_records,
-            expected_columns=plan.expected_columns,
-        )
-    else:
-        fpr = validation_mod.fields_per_record(
-            flat_classes, rec_for_index, plan.materialize.max_records
-        ).at[0].add(col_seed)
-        n_local = jnp.sum(flat_classes == RECORD_DELIM).astype(jnp.int32)
-        val = stitch.validation_fn(
-            fpr, n_local, end_state, jnp.any(ctx.saw_invalid), n_total
-        )
+    with jax.named_scope("stage.validate"):
+        end_state = ctx.end_states[-1]
+        flat_classes = ctx.classes.reshape(-1)
+        if stitch is None:
+            val = validation_mod.validate(
+                flat_classes, rec_for_index, end_state, ctx.saw_invalid,
+                cfg.dfa, plan.materialize.max_records,
+                expected_columns=plan.expected_columns,
+            )
+        else:
+            fpr = validation_mod.fields_per_record(
+                flat_classes, rec_for_index, plan.materialize.max_records
+            ).at[0].add(col_seed)
+            n_local = jnp.sum(flat_classes == RECORD_DELIM).astype(jnp.int32)
+            val = stitch.validation_fn(
+                fpr, n_local, end_state, jnp.any(ctx.saw_invalid), n_total
+            )
 
+    with jax.named_scope("stage.carry"):
+        end_state = end_state.astype(jnp.int32)
+        last_record_end = locate_carry(flat_classes)
     return ParseResult(
         css=cols.css,
         col_start=cols.col_start,
@@ -462,8 +471,8 @@ def execute_plan(
         field_present=cols.findex.present,
         values=values,
         validation=val,
-        end_state=end_state.astype(jnp.int32),
-        last_record_end=locate_carry(flat_classes),
+        end_state=end_state,
+        last_record_end=last_record_end,
     )
 
 
@@ -531,41 +540,47 @@ def materialize(
     of validity.
     """
     n_cols = plan.n_cols
-    flat_classes = classes.reshape(-1)
-
     selected = np.asarray(plan.selected) if plan.selected is not None else None
-    tagged = tagging_mod.tag_symbols(
-        raw_chunks, flat_classes, record_id, column_id, n_cols,
-        plan.tagging, selected_mask=selected,
-    )
+    with jax.named_scope("stage.tag"):
+        tagged = tagging_mod.tag_symbols(
+            raw_chunks, classes.reshape(-1), record_id, column_id, n_cols,
+            plan.tagging, selected_mask=selected,
+        )
 
-    part = backend.partition(tagged.col_tag, n_cols, plan.partition_impl, cfg)
-    if plan.tagging == "tagged":
-        # delim_flag is structurally all-False in tagged mode: skip one
-        # N-sized gather+write
-        css, rec_sorted, col_sorted = partition_mod.apply_partition(
-            part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag
+    with jax.named_scope("stage.partition"):
+        part = backend.partition(tagged.col_tag, n_cols, plan.partition_impl,
+                                 cfg)
+    with jax.named_scope("stage.gather"):
+        if plan.tagging == "tagged":
+            # delim_flag is structurally all-False in tagged mode: skip one
+            # N-sized gather+write
+            css, rec_sorted, col_sorted = partition_mod.apply_partition(
+                part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag
+            )
+            flag_sorted = None
+        else:
+            css, rec_sorted, col_sorted, flag_sorted = (
+                partition_mod.apply_partition(
+                    part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag,
+                    tagged.delim_flag,
+                ))
+    with jax.named_scope("stage.fields"):
+        findex = fields_mod.field_index(
+            plan.tagging, col_sorted, rec_sorted, part.col_start, n_cols,
+            plan.max_records, term_flag=flag_sorted,
         )
-        flag_sorted = None
-    else:
-        css, rec_sorted, col_sorted, flag_sorted = partition_mod.apply_partition(
-            part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag,
-            tagged.delim_flag,
-        )
-    findex = fields_mod.field_index(
-        plan.tagging, col_sorted, rec_sorted, part.col_start, n_cols,
-        plan.max_records, term_flag=flag_sorted,
-    )
     cols = ColumnBatch(css, part.col_start, part.col_count, findex)
 
     values: Dict[str, typeconv_mod.Parsed] = {}
-    for name, c, dtype in plan.convert:
-        p = backend.parse_field[dtype](
-            css, findex.offset[c], findex.length[c], cfg
-        )
-        if dtype != "str":
-            p = p._replace(value=jnp.where(p.valid, p.value, jnp.zeros_like(p.value)))
-        values[name] = p
+    with jax.named_scope("stage.convert"):
+        for name, c, dtype in plan.convert:
+            p = backend.parse_field[dtype](
+                css, findex.offset[c], findex.length[c], cfg
+            )
+            if dtype != "str":
+                p = p._replace(value=jnp.where(p.valid, p.value,
+                                               jnp.zeros_like(p.value)))
+            values[name] = p
     return cols, values
 
 

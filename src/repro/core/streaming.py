@@ -50,6 +50,7 @@ gather+convert typeconv kernels with zero changes here.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Iterable, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -59,6 +60,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from jax import shard_map
 
+from repro.core import spans
 from repro.core import stages as stages_mod
 from repro.core.dfa import PAD_BYTE
 from repro.core.parser import ParseResult, Parser
@@ -69,6 +71,10 @@ from repro.core.parser import ParseResult, Parser
 #: ``int(...)``/``.item()``/``np.asarray`` syncs).  Tests monkeypatch it to
 #: count fetches and assert they trail dispatches by one partition.
 _device_get = jax.device_get
+
+#: Ids of ``parse_streams`` calls, process-wide: every host span of a call
+#: carries ``call=<id>`` (``repro.core.spans``).
+_CALL_IDS = itertools.count(1)
 
 
 class StreamOverflow(ValueError):
@@ -164,8 +170,10 @@ class _Feed:
     returns ``None`` and the stream's lane goes inert.
     """
 
-    def __init__(self, source: Iterable[bytes], partition_bytes: int):
+    def __init__(self, source: Iterable[bytes], partition_bytes: int,
+                 call: int):
         self._it = iter(source)
+        self._call = call
         self._buf = b""
         self._pb = partition_bytes
         self.exhausted = False
@@ -180,9 +188,12 @@ class _Feed:
             return None
         while not self.exhausted and len(self._buf) < self._pb:
             try:
-                self._buf += next(self._it)
+                with spans.span("stream.pull", call=self._call):
+                    piece = next(self._it)
             except StopIteration:
                 self.exhausted = True
+            else:
+                self._buf += piece
         take, self._buf = self._buf[: self._pb], self._buf[self._pb:]
         flush = self.exhausted and not self._buf
         if flush:
@@ -302,28 +313,34 @@ class StreamSession:
         capacity = self.capacity
 
         def step_one(carry_buf, carry_len, fresh, fresh_len, flush):
-            # The host transfers only the partition-sized fresh bytes;
-            # extend to the carry capacity on-device (PAD tail, fused into
-            # the splice by XLA — nothing extra crosses the bus).
-            pad = capacity - fresh.shape[-1]
-            if pad:
-                fresh = jnp.concatenate(
-                    [fresh, jnp.full((pad,), PAD_BYTE, jnp.uint8)])
-            buf, total, overflow = backend.prepend_carry(
-                carry_buf, carry_len, fresh, fresh_len, flush, cfg
-            )
+            # The carry work runs under the same ``stage.*`` scopes as the
+            # stages of execute_plan (core/stages.py).
+            with jax.named_scope("stage.carry"):
+                # The host transfers only the partition-sized fresh bytes;
+                # extend to the carry capacity on-device (PAD tail, fused
+                # into the splice by XLA — nothing extra crosses the bus).
+                pad = capacity - fresh.shape[-1]
+                if pad:
+                    fresh = jnp.concatenate(
+                        [fresh, jnp.full((pad,), PAD_BYTE, jnp.uint8)])
+                buf, total, overflow = backend.prepend_carry(
+                    carry_buf, carry_len, fresh, fresh_len, flush, cfg
+                )
+            with jax.named_scope("stage.contexts"):
+                chunks = buf.reshape(-1, k)
             # execute_plan dispatches staged vs fused (the whole-pipeline
             # megakernel) per the resolved plan — the carry hooks above/
             # below are path-agnostic, so fuse_pipeline streams for free.
-            result = stages_mod.execute_plan(buf.reshape(-1, k), plan, cfg, backend)
-            new_buf, new_len = backend.extract_carry(
-                buf, total, result.last_record_end, flush, cfg
-            )
-            aux = _StepAux(
-                n_records=result.validation.n_records.astype(jnp.int32),
-                last_record_end=result.last_record_end,
-                overflow=overflow,
-            )
+            result = stages_mod.execute_plan(chunks, plan, cfg, backend)
+            with jax.named_scope("stage.carry"):
+                new_buf, new_len = backend.extract_carry(
+                    buf, total, result.last_record_end, flush, cfg
+                )
+                aux = _StepAux(
+                    n_records=result.validation.n_records.astype(jnp.int32),
+                    last_record_end=result.last_record_end,
+                    overflow=overflow,
+                )
             return result, new_buf, new_len, aux
 
         fn = step_one if not self._batched else jax.vmap(step_one)
@@ -418,6 +435,15 @@ class StreamSession:
         are finalized with ``failed=True``), and every other lane parses
         to completion exactly as if the failed lane had never been there.
         No exception crosses lane boundaries.
+
+        **Host spans** (``repro.core.spans``), each with ``call=<id>`` of
+        this call: per round ``stream.stage`` (staging; ``bytes``: the fresh
+        take bytes) with a ``stream.pull`` child per read of a source,
+        ``stream.dispatch`` (the step call), and ``stream.drain`` (the
+        one-behind read and its bookkeeping; ``carry_bytes``: the carry
+        the round re-parsed, ``records``) with its ``stream.wait`` child
+        (the fetch of the round's scalars).  No span is open while the
+        caller holds a result.
         """
         if self._state != "idle":
             raise RuntimeError(
@@ -432,14 +458,17 @@ class StreamSession:
         self._state = "active"
         self.call_stats = tuple(StreamStats() for _ in range(S))
         self._failed = [False] * S
+        call = next(_CALL_IDS)
         done = False
         try:
-            feeds = [_Feed(src, self.partition_bytes) for src in sources]
+            feeds = [_Feed(src, self.partition_bytes, call) for src in sources]
             carry_buf, carry_len = self._init_carry()
             carry_known = [0] * S  # host mirror of carry_len, one round behind
             pending = None
             while True:
-                staged = self._stage_round(feeds)
+                with spans.span("stream.stage", call=call) as sp:
+                    staged = self._stage_round(feeds)
+                    sp.set(bytes=0 if staged is None else int(staged[1].sum()))
                 if staged is None:
                     break
                 fresh, fresh_len, flush, active, delims = staged
@@ -447,17 +476,18 @@ class StreamSession:
                 # the previous round's carry outputs, so they must not be
                 # retained (reset() would try to block on dead buffers).
                 self._inflight = None
-                result, carry_buf, carry_len, aux = self._step(
-                    carry_buf, carry_len, fresh,
-                    jnp.asarray(fresh_len if self._batched else fresh_len[0]),
-                    jnp.asarray(flush if self._batched else flush[0]),
-                )
+                with spans.span("stream.dispatch", call=call):
+                    result, carry_buf, carry_len, aux = self._step(
+                        carry_buf, carry_len, fresh,
+                        jnp.asarray(fresh_len if self._batched else fresh_len[0]),
+                        jnp.asarray(flush if self._batched else flush[0]),
+                    )
                 self._inflight = (result, carry_buf, carry_len, aux)
                 if pending is not None:
-                    yield from self._drain(pending, carry_known, feeds)
+                    yield from self._drain(pending, carry_known, feeds, call)
                 pending = (result, aux, fresh_len, flush, active, delims)
             if pending is not None:
-                yield from self._drain(pending, carry_known, feeds)
+                yield from self._drain(pending, carry_known, feeds, call)
             done = True
         finally:
             if done:
@@ -491,61 +521,71 @@ class StreamSession:
             self._inflight = None
         self._state = "idle"
 
-    def _drain(self, pending, carry_known: List[int], feeds: List[_Feed]):
-        """Fetch one round's scalars (the one-behind read) and yield its
-        per-stream results; overflowing lanes yield a typed
+    def _drain(self, pending, carry_known: List[int], feeds: List[_Feed],
+               call: int) -> List[Tuple[int, object, int]]:
+        """Fetch one round's scalars (the one-behind read) and return its
+        per-stream results; overflowing lanes get a typed
         :class:`StreamOverflow` and are retired without disturbing the
-        rest of the batch."""
+        rest of the batch.  Returned, not yielded, so that the round's
+        ``stream.drain`` span closes before the caller sees a result."""
         result, aux, fresh_len, flush, active, delims = pending
-        aux_np = _device_get(aux)
-        n_records = np.atleast_1d(aux_np.n_records)
-        last_end = np.atleast_1d(aux_np.last_record_end)
-        overflow = np.atleast_1d(aux_np.overflow)
-        for s in range(self.n_streams):
-            if not active[s] or self._failed[s]:
-                # Inert lane, or a failed lane's already-dispatched round
-                # (dispatch runs one ahead of the drain that detects the
-                # overflow): its buffer contents are garbage — suppress.
-                continue
-            take_len, carry_in = int(fresh_len[s]), carry_known[s]
-            if take_len == 0 and carry_in == 0:
-                # The optimistic end-of-stream flush round found nothing to
-                # parse (the source ended exactly at a partition boundary,
-                # or was empty): a no-op, not a partition.
-                carry_known[s] = 0
-                continue
-            if bool(overflow[s]):
-                # Per-lane fault: the splice wrapped, this lane's buffer is
-                # garbage.  Retire the lane (its feed stops producing; the
-                # next parse_streams call re-inits carry device-side) and
-                # report on this stream's channel only.
-                err = StreamOverflow(
-                    s, carry_in + take_len + (1 if flush[s] else 0),
-                    self.capacity, self.n_streams)
-                self._failed[s] = True
-                feeds[s].kill()
-                carry_known[s] = 0
+        out = []
+        with spans.span("stream.drain", call=call) as sp:
+            with spans.span("stream.wait", call=call):
+                aux_np = _device_get(aux)
+            n_records = np.atleast_1d(aux_np.n_records)
+            last_end = np.atleast_1d(aux_np.last_record_end)
+            overflow = np.atleast_1d(aux_np.overflow)
+            carry_bytes = records = 0
+            for s in range(self.n_streams):
+                if not active[s] or self._failed[s]:
+                    # Inert lane, or a failed lane's already-dispatched round
+                    # (dispatch runs one ahead of the drain that detects the
+                    # overflow): its buffer contents are garbage — suppress.
+                    continue
+                take_len, carry_in = int(fresh_len[s]), carry_known[s]
+                if take_len == 0 and carry_in == 0:
+                    # The optimistic end-of-stream flush round found nothing
+                    # to parse (the source ended exactly at a partition
+                    # boundary, or was empty): a no-op, not a partition.
+                    carry_known[s] = 0
+                    continue
+                carry_bytes += carry_in
+                if bool(overflow[s]):
+                    # Per-lane fault: the splice wrapped, this lane's buffer
+                    # is garbage.  Retire the lane (its feed stops producing;
+                    # the next parse_streams call re-inits carry
+                    # device-side) and report on this stream's channel only.
+                    err = StreamOverflow(
+                        s, carry_in + take_len + (1 if flush[s] else 0),
+                        self.capacity, self.n_streams)
+                    self._failed[s] = True
+                    feeds[s].kill()
+                    carry_known[s] = 0
+                    for st in (self.stats[s], self.call_stats[s]):
+                        st.bytes_in += take_len
+                        st.bytes_reparsed += carry_in
+                        st.failed = True
+                    out.append((s, err, 0))
+                    continue
+                # Mirror of extract_carry: the carry length re-derived from
+                # host-known values + the fetched boundary (the donated
+                # device carry_len itself is never read back).
+                carry_out = 0 if flush[s] else max(
+                    carry_in + take_len - (int(last_end[s]) + 1), 0)
                 for st in (self.stats[s], self.call_stats[s]):
+                    st.partitions += 1
                     st.bytes_in += take_len
                     st.bytes_reparsed += carry_in
-                    st.failed = True
-                yield s, err, 0
-                continue
-            # Mirror of extract_carry: the carry length re-derived from
-            # host-known values + the fetched boundary (the donated device
-            # carry_len itself is never read back).
-            carry_out = 0 if flush[s] else max(
-                carry_in + take_len - (int(last_end[s]) + 1), 0)
-            for st in (self.stats[s], self.call_stats[s]):
-                st.partitions += 1
-                st.bytes_in += take_len
-                st.bytes_reparsed += carry_in
-                st.records += int(n_records[s])
-                st.max_carry = max(st.max_carry, carry_out)
-                if flush[s] and delims[s]:
-                    st.flush_delims += 1
-            carry_known[s] = carry_out
-            yield s, self._slice_result(result, s), int(n_records[s])
+                    st.records += int(n_records[s])
+                    st.max_carry = max(st.max_carry, carry_out)
+                    if flush[s] and delims[s]:
+                        st.flush_delims += 1
+                carry_known[s] = carry_out
+                records += int(n_records[s])
+                out.append((s, self._slice_result(result, s), int(n_records[s])))
+            sp.set(carry_bytes=carry_bytes, records=records)
+        return out
 
     def _slice_result(self, result: ParseResult, s: int) -> ParseResult:
         if not self._batched:

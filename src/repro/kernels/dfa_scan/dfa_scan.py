@@ -128,6 +128,7 @@ def chunk_vectors(
         out_shape=jax.ShapeDtypeStruct((s, c), jnp.int32),
         scratch_shapes=_scratch(k, bc),
         interpret=interpret,
+        name="dfa_chunk_vectors",
     )(chunks.T, tt)
     return vecs.T
 
@@ -214,6 +215,7 @@ def _replay_call(chunks, start_states, dfa, block_chunks, interpret,
         out_shape=out_shape,
         scratch_shapes=_scratch(k, bc) * 2,
         interpret=interpret,
+        name="dfa_replay",
     )(chunks.T, start_states.astype(jnp.int32)[None, :])
     return (outs[0].T, outs[1][0]) + tuple(o.T for o in outs[2:])
 

@@ -241,6 +241,7 @@ def _call_rowwise(arith, field_bytes, lengths, block_rows, val_dtype,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="numparse_rowwise",
     )(field_bytes.T, _row(lengths))
     return val[0], ok[0].astype(bool)
 
@@ -279,6 +280,7 @@ def _windowed_call(arith, css, rel_off, lengths, win_start, width, block_rows,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="numparse_windowed",
     )(win_start.astype(jnp.int32) // WINDOW_ALIGN, css_p, _row(rel_off),
       _row(lengths))
     return val[0], ok[0].astype(bool)
@@ -321,6 +323,7 @@ def _per_row_call(arith, css, offsets, lengths, width, val_dtype, interpret):
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=interpret,
+        name="numparse_per_row",
     )
 
     # Pallas batches a kernel by adding a grid axis, which an HBM-resident

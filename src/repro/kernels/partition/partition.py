@@ -121,6 +121,7 @@ def partition_blocks(
         ],
         scratch_shapes=[pltpu_vmem((1, n_parts), jnp.int32)],
         interpret=interpret,
+        name="radix_partition",
     )(tags)
     return rel, count[0]
 
