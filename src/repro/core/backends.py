@@ -14,7 +14,8 @@ swappable stage implementations:
     stream by column tag.  Receives the *resolved* ``partition_impl``
     (``stages.plan_materialize`` maps ``"auto"`` to the backend's
     ``default_partition_impl``; ``partition_impls`` lists what the backend
-    accepts).
+    accepts).  ``"sort"`` never reaches it: both backends list it and
+    ``stages.materialize`` runs it directly (``core.partition``).
   * ``parse_field``       — §3.3 type conversion, one entry per schema dtype
     (``int32`` / ``float32`` / ``date`` / ``str``), each mapping
     ``(css, offset, length)`` to a :class:`typeconv.Parsed`.
@@ -49,10 +50,11 @@ Backends:
   * ``pallas``    — the Pallas TPU kernels (``kernels.dfa_scan`` /
     ``kernels.partition`` / ``kernels.numparse``).  The fused replay kernel
     makes the separate ``chunk_summaries`` jnp pass disappear; the
-    partition defaults to the single-pass radix kernel on a TPU
-    (``partition_impl="auto"`` → ``"kernel"``; off the chip the kernels run
-    interpreted and "auto" resolves to the jit-fused jnp radix pass, with
-    the kernel selectable explicitly); and int32/float32/date columns convert
+    partition defaults on a TPU to one stable sort by column tag that
+    carries the payloads (``partition_impl="auto"`` → ``"sort"``; off the
+    chip the kernels run interpreted and "auto" resolves to the jit-fused
+    jnp radix pass; the single-pass radix kernel stays selectable as
+    ``"kernel"``); and int32/float32/date columns convert
     inside *fused gather+convert* ``numparse`` kernels that index the CSS
     in-kernel — no XLA ``take``/gather between the field index and
     conversion.  The fused kernels are *windowed* by default: each row
@@ -182,6 +184,7 @@ class ParseBackend:
               offsets.ChunkSummary)
       partition(col_tag (N,) i32, n_cols, impl: str, cfg)
           -> partition.Partitioned      for impl in ``partition_impls``
+                                        other than ``"sort"``
       parse_field[dtype](css (N,) u8, offset (R,) i32, length (R,) i32, cfg)
           -> typeconv.Parsed     for dtype in int32 | float32 | date | str
 
@@ -331,7 +334,7 @@ REFERENCE = register_backend(ParseBackend(
         "date": _ref_parse_date,
         "str": _shared_parse_str,
     },
-    partition_impls=tuple(sorted(partition_mod.PARTITION_IMPLS)),
+    partition_impls=tuple(sorted(partition_mod.PARTITION_IMPLS)) + ("sort",),
     default_partition_impl=lambda cfg: "scatter",
 ))
 
@@ -582,14 +585,18 @@ PALLAS = register_backend(ParseBackend(
         "date": _pl_parse_date,
         "str": _shared_parse_str,
     },
-    partition_impls=tuple(sorted(partition_mod.PARTITION_IMPLS)) + ("kernel",),
-    # "auto" resolution: the radix kernel on a TPU; off the chip the kernel
-    # runs op-by-op in the Pallas interpreter, where XLA's jit-fused radix
-    # pass (scatter2) is strictly faster — the kernel stays selectable
-    # (partition_impl="kernel") and is pinned bit-identical by the
-    # parity/fuzz/golden suites.
+    partition_impls=(tuple(sorted(partition_mod.PARTITION_IMPLS))
+                     + ("kernel", "sort")),
+    # "auto" resolution: on a TPU, "sort" — one stable sort by column tag
+    # that carries the symbols and tags along, in place of a permutation
+    # (the radix kernel's, inverted by a scatter) and a gather per payload
+    # through it, which cost many times the sort there.  Off the chip the
+    # kernels run op-by-op in the Pallas interpreter and gathers are cheap:
+    # XLA's jit-fused radix pass (scatter2) is the fastest there.  "kernel"
+    # and "sort" stay selectable everywhere and are pinned bit-identical
+    # by the parity/fuzz/golden suites.
     default_partition_impl=lambda cfg: (
-        "scatter2" if interpret_kernels() else "kernel"),
+        "scatter2" if interpret_kernels() else "sort"),
     typeconv_path=_pl_typeconv_path,
     config_key=_pl_config_key,
     interpret=lambda: interpret_kernels(),

@@ -89,9 +89,13 @@ class ParserConfig:
         inline in the CSS) or ``vector`` (separate terminator bit vector).
     ``partition_impl``
         §3.3 stable-partition implementation: ``auto`` (backend-resolved —
-        see ``backends.default_partition_impl``), ``argsort``, ``scatter``,
-        ``scatter2`` (jnp radix variants) or ``kernel`` (single-pass Pallas
-        radix kernel, pallas backend only).
+        see ``backends.default_partition_impl``: ``sort`` on pallas on a
+        TPU, ``scatter2`` on pallas elsewhere, ``scatter`` on reference),
+        ``sort`` (one stable sort by column tag carrying the symbols and
+        tags, no permutation; both backends), ``argsort``, ``scatter``,
+        ``scatter2`` (jnp radix variants, gathered through a permutation)
+        or ``kernel`` (single-pass Pallas radix kernel, pallas backend
+        only).
     ``use_matmul_scan``
         §3.1 composite scan as one-hot matmuls instead of gathers (the
         paper's SpMV formulation; useful where gathers are slow).
@@ -163,8 +167,9 @@ class ParserConfig:
     max_records: int
     chunk_size: int = 64
     tagging: str = "tagged"          # tagged | inline | vector
-    partition_impl: str = "auto"     # auto | argsort | scatter | scatter2 |
-                                     # kernel (backend-resolved; stages.py)
+    partition_impl: str = "auto"     # auto | sort | argsort | scatter |
+                                     # scatter2 | kernel (backend-resolved;
+                                     # stages.py)
     use_matmul_scan: bool = False
     int_width: int = 11
     float_width: int = 24

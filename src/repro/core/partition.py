@@ -1,20 +1,34 @@
 """Stable partition of symbols by column tag → concatenated symbol strings
 (paper §3.3).
 
-The paper uses a stable radix sort over column tags (CUB).  Column counts in
-delimiter-separated data are tiny (≤ a few dozen), so a single
-histogram + prefix-sum + scatter pass — exactly one radix pass — suffices.
-Two TPU-friendly implementations:
+The paper uses a stable radix sort over column tags (CUB).  A stable
+partition by column tag *is* a stable sort by column tag, and its result is
+unique, so every implementation here is bit-identical.  Two forms:
 
-  * ``partition_argsort``  — XLA's stable sort network over the tag key.
-    O(N log² N) comparator depth but a single fused op; the robust default.
-  * ``partition_scatter``  — the paper's radix pass made explicit: one-hot
-    histogram, exclusive prefix sum for column starts, rank-within-column via
-    a (N × n_cols+1) cumsum, then a scatter.  O(N·C) work, all dense vector
-    ops; wins for small C (§Perf measures the crossover).
+  * ``"sort"`` (``column_layout`` + ``sort_partition``) — one stable
+    ``lax.sort`` keyed on the column tag that carries the payloads (symbols,
+    record tags, delimiter flags) along, so the sorted key and payloads come
+    out directly and no permutation is built, inverted or gathered through
+    (keyed on ``col_tag * N + position`` where that fits int32, which is
+    unique and needs no stability).
+    The column counts come from a dense compare-and-reduce (no
+    ``bincount``, which is a scatter-add on a TPU).  ``"auto"`` picks it
+    on a TPU, where one sort costs a fraction of the gathers it replaces.
+  * gather form (``PARTITION_IMPLS``, plus the Pallas radix kernel in
+    ``kernels/partition``) — build the permutation, then ``apply_partition``
+    gathers each payload through it.  Column counts in delimiter-separated
+    data are tiny (≤ a few dozen), so a single histogram + prefix-sum +
+    scatter pass — exactly one radix pass — suffices:
 
-Both return the permutation so callers can carry any payload (symbols,
-record tags, delimiter flags) through the same reordering.
+      - ``partition_argsort``  — XLA's stable sort network over the tag key,
+        then a gather per payload.
+      - ``partition_scatter``  — the paper's radix pass made explicit:
+        one-hot histogram, exclusive prefix sum for column starts,
+        rank-within-column via a (N × n_cols+1) cumsum, then a scatter that
+        inverts the destinations into the permutation.  O(N·C) work, all
+        dense vector ops (the reference backend's default).
+      - ``partition_scatter2`` — the same pass blocked so ranks fit uint8
+        (the default where the Pallas kernels run interpreted).
 """
 from __future__ import annotations
 
@@ -112,6 +126,36 @@ def apply_partition(perm: jax.Array, *arrays: jax.Array):
     """Gather any number of parallel payload arrays through ``perm``."""
     out = tuple(a.reshape(-1)[perm] for a in arrays)
     return out if len(out) != 1 else out[0]
+
+
+def column_layout(col_tag: jax.Array, n_cols: int):
+    """``(col_start, col_count)``, each ``(n_cols+1,)`` int32 with the
+    sentinel drop column last, for ``partition_impl="sort"``: counts by a
+    dense compare of every tag against every column and a reduce."""
+    cols = jnp.arange(n_cols + 1, dtype=jnp.int32)
+    count = (col_tag[None, :] == cols[:, None]).sum(axis=1, dtype=jnp.int32)
+    start = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(count)[:-1]])
+    return start, count
+
+
+def sort_partition(col_tag: jax.Array, n_cols: int, *payloads: jax.Array):
+    """``partition_impl="sort"``: one stable sort keyed on ``col_tag`` that
+    carries ``payloads`` along.  Returns ``(col_sorted, *payloads_sorted)``,
+    exactly what ``apply_partition`` gives for the same tags under any
+    gather-form implementation.
+
+    Where every ``col_tag * n + position`` fits int32 (decided from the
+    static shape), that unique key sorts unstably to the same order and
+    spares the sort the position operand XLA adds for stability."""
+    n = col_tag.shape[0]
+    payloads = tuple(a.reshape(-1) for a in payloads)
+    if 0 < n and (n_cols + 1) * n < 2**31:
+        key = col_tag * n + jnp.arange(n, dtype=jnp.int32)
+        key, *out = jax.lax.sort((key, *payloads), num_keys=1,
+                                 is_stable=False)
+        return (key // n, *out)
+    return tuple(jax.lax.sort((col_tag, *payloads), num_keys=1,
+                              is_stable=True))
 
 
 PARTITION_IMPLS = {

@@ -35,8 +35,10 @@ change (new stage, new fusion) propagates to all of them at once.
 
 Materialization is a backend responsibility, not driver glue: drivers pass
 the plan through and receive a :class:`ColumnBatch` plus converted values.
-On ``backend="pallas"`` the partition runs the two-pass radix kernel
-(``kernels.partition``) and every typed column converts in a fused
+On ``backend="pallas"`` on a TPU the partition is one stable sort by
+column tag that carries the symbols and record tags along
+(``partition_impl="sort"``, ``core.partition``) and every typed column
+converts in a fused
 gather+convert kernel (``kernels.numparse``) that indexes the CSS in-kernel
 — no XLA ``take``/gather between the field index and conversion.  The
 fused kernels DMA one contiguous CSS *window* per row block (sorted
@@ -100,7 +102,8 @@ class MaterializePlan(NamedTuple):
     """
 
     tagging: str                                # tagged | inline | vector
-    partition_impl: str                         # argsort|scatter|scatter2|kernel
+    partition_impl: str                         # argsort|scatter|scatter2|
+                                                # kernel|sort
     n_cols: int
     max_records: int
     selected: Optional[Tuple[bool, ...]]        # None = every column selected
@@ -114,8 +117,8 @@ def plan_materialize(cfg, backend: ParseBackend, *, convert: bool = True
     """Resolve ``cfg`` into a :class:`MaterializePlan` for ``backend``.
 
     ``partition_impl="auto"`` becomes the backend's platform-aware default
-    (on ``pallas``: the radix kernel on a TPU, the jit-fused jnp radix pass
-    where the kernels run interpreted); explicit impls are
+    (on ``pallas``: the payload-carrying sort on a TPU, the jit-fused jnp
+    radix pass where the kernels run interpreted); explicit impls are
     validated against ``backend.partition_impls`` so typos and
     backend-foreign impls fail at config time, not under jit.  The
     windowed-DMA knobs (``cfg.window_rows`` / ``cfg.max_window_bytes``,
@@ -532,7 +535,10 @@ def materialize(
     ``record_id`` is whatever the caller wants in the field index: global
     ids for the single-device parser, shard-local ids for the distributed
     one.  The partition and every per-dtype conversion dispatch through the
-    backend (``backend.partition`` / ``backend.parse_field``); invalid
+    backend (``backend.partition`` / ``backend.parse_field``), except
+    ``partition_impl="sort"``, which both backends share: it sorts the
+    payloads into column order itself instead of gathering them through a
+    permutation (``core.partition.sort_partition``); invalid
     numeric values are normalised to 0 so backends agree bit-for-bit (their
     Horner loops treat non-digit garbage differently, and garbage values
     are meaningless anyway — ``valid`` gates them).  ``str`` is exempt: its
@@ -547,29 +553,34 @@ def materialize(
             plan.tagging, selected_mask=selected,
         )
 
-    with jax.named_scope("stage.partition"):
-        part = backend.partition(tagged.col_tag, n_cols, plan.partition_impl,
-                                 cfg)
-    with jax.named_scope("stage.gather"):
-        if plan.tagging == "tagged":
-            # delim_flag is structurally all-False in tagged mode: skip one
-            # N-sized gather+write
-            css, rec_sorted, col_sorted = partition_mod.apply_partition(
-                part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag
-            )
-            flag_sorted = None
-        else:
-            css, rec_sorted, col_sorted, flag_sorted = (
-                partition_mod.apply_partition(
-                    part.perm, tagged.symbol, tagged.rec_tag, tagged.col_tag,
-                    tagged.delim_flag,
-                ))
+    payloads = (tagged.symbol, tagged.rec_tag)
+    if plan.tagging != "tagged":
+        # delim_flag is structurally all-False in tagged mode: move it into
+        # column order only where the field index reads it
+        payloads += (tagged.delim_flag,)
+    if plan.partition_impl == "sort":
+        # one stable sort by column tag carries the payloads: no permutation
+        with jax.named_scope("stage.partition"):
+            col_start, col_count = partition_mod.column_layout(
+                tagged.col_tag, n_cols)
+        with jax.named_scope("stage.gather"):
+            col_sorted, css, rec_sorted, *flag = partition_mod.sort_partition(
+                tagged.col_tag, n_cols, *payloads)
+    else:
+        with jax.named_scope("stage.partition"):
+            part = backend.partition(tagged.col_tag, n_cols,
+                                     plan.partition_impl, cfg)
+            col_start, col_count = part.col_start, part.col_count
+        with jax.named_scope("stage.gather"):
+            col_sorted, css, rec_sorted, *flag = partition_mod.apply_partition(
+                part.perm, tagged.col_tag, *payloads)
+    flag_sorted = flag[0] if flag else None
     with jax.named_scope("stage.fields"):
         findex = fields_mod.field_index(
-            plan.tagging, col_sorted, rec_sorted, part.col_start, n_cols,
+            plan.tagging, col_sorted, rec_sorted, col_start, n_cols,
             plan.max_records, term_flag=flag_sorted,
         )
-    cols = ColumnBatch(css, part.col_start, part.col_count, findex)
+    cols = ColumnBatch(css, col_start, col_count, findex)
 
     values: Dict[str, typeconv_mod.Parsed] = {}
     with jax.named_scope("stage.convert"):

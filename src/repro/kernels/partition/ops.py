@@ -6,9 +6,11 @@ a whole number of blocks with the sentinel column, run the single-pass
 Pallas radix kernel (per-block histograms + running carry + intra-block
 ranks → column-relative destinations), lift the relative destinations to
 global ones with the tiny ``(n_cols+1,)`` exclusive prefix, and invert the
-destination map into the gather-form permutation every ``partition_impl``
-returns — so the kernel path is drop-in interchangeable with the jnp impls
-and bit-identical to them (a stable partition's permutation is unique).
+destination map into the gather-form permutation every gather-form
+``partition_impl`` returns — so the kernel path is drop-in interchangeable
+with the jnp impls and bit-identical to them (a stable partition's
+permutation is unique).  On a TPU ``"auto"`` picks ``"sort"`` instead
+(``core/partition.py``), which builds no permutation.
 """
 from __future__ import annotations
 
@@ -61,10 +63,13 @@ def partition_tags(
 
     # The radix pass's scatter: invert dest into gather form (XLA owns the
     # irregular write — see kernels/partition/partition.py docstring).
-    # This is the staged path's one remaining HBM round-trip; the fused
-    # whole-pipeline megakernel (kernels/fused_pipeline/) never builds the
-    # permutation at all — it consumes dest directly in apply form
-    # (css[dest[i]] = sym[i]), which is equivalent because dest is the
-    # inverse of perm by construction.
+    # This inversion, and the gathers through perm it feeds, are the
+    # "kernel" path's HBM round-trips.  Two paths never build the
+    # permutation: the fused whole-pipeline megakernel
+    # (kernels/fused_pipeline/) consumes dest directly in apply form
+    # (css[dest[i]] = sym[i]), equivalent because dest is the inverse of
+    # perm by construction; and partition_impl="sort" (core/partition.py,
+    # the TPU default) sorts the payloads by column tag, which on a TPU
+    # costs a fraction of the inversion plus gathers.
     perm = jnp.zeros((n,), jnp.int32).at[dest].set(jnp.arange(n, dtype=jnp.int32))
     return Partitioned(perm, start.astype(jnp.int32), count.astype(jnp.int32))

@@ -77,9 +77,9 @@ SPACE: Tuple[Knob, ...] = (
         "partition_impl", "partition", "auto",
         lambda be: be.partition_impls,
         "§3.3 stable-partition implementation (jnp radix variants vs the "
-        "Pallas radix kernel).  The hand heuristic — scatter on reference, "
-        "scatter2 off the chip/kernel on a TPU on pallas — becomes "
-        "the cold-cache default.",
+        "Pallas radix kernel vs one payload-carrying sort).  The hand "
+        "heuristic — scatter on reference, scatter2 off the chip/sort on a "
+        "TPU on pallas — becomes the cold-cache default.",
     ),
     Knob(
         "fuse_pipeline", "pipeline", None,

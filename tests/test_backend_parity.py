@@ -138,6 +138,46 @@ def test_streaming_parity_multi_partition():
         _assert_results_equal(r, q, label="stream: ")
 
 
+@pytest.mark.parametrize("be", ("reference", "pallas"))
+@pytest.mark.parametrize("tagging", ("tagged", "inline", "vector"))
+def test_parser_parity_sort(be, tagging):
+    """``partition_impl="sort"`` (the TPU default on pallas, shared by both
+    backends) parses bit-identically to ``"scatter"``."""
+    data = INPUTS["csv"]
+    res = {}
+    for impl in ("scatter", "sort"):
+        p = Parser(ParserConfig(dfa=make_csv_dfa(), schema=SCHEMAS["csv"],
+                                backend=be, partition_impl=impl,
+                                tagging=tagging, max_records=16,
+                                chunk_size=16))
+        assert p.plan.materialize.partition_impl == impl
+        res[impl] = p.parse(data)
+    _assert_results_equal(res["scatter"], res["sort"],
+                          label=f"{be}/{tagging}/sort: ")
+
+
+@pytest.mark.parametrize("be", ("reference", "pallas"))
+def test_stream_session_parity_sort(be):
+    """``StreamSession`` with two batched streams (the step under ``vmap``)
+    gives the same partitions with ``"sort"`` as with ``"scatter"``."""
+    from repro.core.streaming import StreamSession
+
+    datas = [INPUTS["csv"] * 3,
+             b"".join(INPUTS["csv"].splitlines(True)[::-1])]
+    outs = {}
+    for impl in ("scatter", "sort"):
+        cfg = ParserConfig(dfa=make_csv_dfa(), schema=SCHEMAS["csv"],
+                           backend=be, partition_impl=impl, max_records=16,
+                           chunk_size=16)
+        sess = StreamSession(Parser(cfg), partition_bytes=64,
+                             max_carry_bytes=64, n_streams=2)
+        outs[impl] = list(sess.parse_streams([[d] for d in datas]))
+    assert len(outs["scatter"]) == len(outs["sort"]) > 2
+    for (s, r, n), (s2, q, n2) in zip(outs["scatter"], outs["sort"]):
+        assert (s, n) == (s2, n2)
+        _assert_results_equal(r, q, label=f"{be}/stream{s}/sort: ")
+
+
 def test_distributed_parity():
     import jax
     from jax.sharding import Mesh

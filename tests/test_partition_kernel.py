@@ -6,6 +6,7 @@ A stable partition's permutation is unique, so the kernel must agree with
 *exactly* — perm, col_start and col_count — for any tag stream, including
 ones that do not divide the kernel's block sizes.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -158,3 +159,129 @@ def test_kernel_partition_stable_property():
         np.testing.assert_array_equal(start, np.concatenate([[0], np.cumsum(count)[:-1]]))
 
     check()
+
+
+# ---------------------------------------------------------------------------
+# partition_impl="sort": one stable sort carrying the payloads, no permutation
+# ---------------------------------------------------------------------------
+
+def _payloads(rng, n):
+    """symbol (u8), rec_tag (i32) and delim_flag (bool), as tagging makes
+    them; ``tagged`` mode carries the first two, ``inline``/``vector`` all
+    three."""
+    return (jnp.asarray(rng.integers(0, 256, size=n), jnp.uint8),
+            jnp.asarray(np.sort(rng.integers(0, max(n // 4, 1), size=n)),
+                        jnp.int32),
+            jnp.asarray(rng.integers(0, 2, size=n).astype(bool)))
+
+
+def _assert_sort_matches(tags, c, payloads, want_part, label):
+    start, count = partition_mod.column_layout(tags, c)
+    got = partition_mod.sort_partition(tags, c, *payloads)
+    want = partition_mod.apply_partition(want_part.perm, tags, *payloads)
+    want = want if isinstance(want, tuple) else (want,)
+    assert len(got) == len(want) == len(payloads) + 1, label
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == w.dtype, f"{label}: output {i} dtype"
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"{label}: output {i}")
+    np.testing.assert_array_equal(np.asarray(start),
+                                  np.asarray(want_part.col_start),
+                                  err_msg=f"{label}: col_start")
+    np.testing.assert_array_equal(np.asarray(count),
+                                  np.asarray(want_part.col_count),
+                                  err_msg=f"{label}: col_count")
+
+
+@pytest.mark.parametrize("n,c", SIZES + [(0, 3)])
+@pytest.mark.parametrize("mode", ["tagged", "inline", "vector"])
+def test_sort_matches_every_gather_impl(n, c, mode):
+    """col_sorted, css, rec_sorted, flag_sorted, col_start and col_count of
+    the sort are exactly ``apply_partition`` through the permutation of
+    every gather-form impl and of the radix kernel."""
+    rng = np.random.default_rng(n * 31 + c)
+    tags = jnp.asarray(rng.integers(0, c + 1, size=n), jnp.int32)
+    payloads = _payloads(rng, n)[:2 if mode == "tagged" else 3]
+    impls = {**partition_mod.PARTITION_IMPLS,
+             "kernel": lambda t, k: part_ops.partition_tags(
+                 t, k, interpret=interpret_kernels())}
+    for name, impl in impls.items():
+        _assert_sort_matches(tags, c, payloads, impl(tags, c),
+                             f"n={n} c={c} {mode} vs {name}")
+
+
+@pytest.mark.parametrize("kind", ["one_column", "sentinel", "round_robin"])
+def test_sort_degenerate_streams(kind):
+    c, n = 3, 300
+    tags_np = {"one_column": np.zeros(n, np.int32),
+               "sentinel": np.full(n, c, np.int32),
+               "round_robin": np.arange(n, dtype=np.int32) % (c + 1)}[kind]
+    tags = jnp.asarray(tags_np)
+    payloads = _payloads(np.random.default_rng(3), n)
+    _assert_sort_matches(tags, c, payloads,
+                         partition_mod.partition_scatter(tags, c), kind)
+
+
+@pytest.mark.parametrize("n,c", SIZES)
+def test_sort_stable_fallback(n, c):
+    """Where ``col_tag * n + position`` could pass int32 the sort keeps the
+    stable sort on the tag itself: the same outputs.  A column count that
+    large is reached here by the static ``n_cols`` alone; the tags stay
+    within it."""
+    rng = np.random.default_rng(n * 7 + c)
+    tags = jnp.asarray(rng.integers(0, c + 1, size=n), jnp.int32)
+    payloads = _payloads(rng, n)
+    huge = -(-2**31 // n)                   # (huge + 1) * n > 2**31
+    got = partition_mod.sort_partition(tags, huge, *payloads)
+    want = partition_mod.sort_partition(tags, c, *payloads)
+    ref = partition_mod.apply_partition(
+        partition_mod.partition_argsort(tags, c).perm, tags, *payloads)
+    for g, w, r in zip(got, want, ref):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_sort_every_column_count():
+    """n_cols from 1 to 31, each against ``partition_argsort`` and numpy's
+    stable argsort and histogram."""
+    n = 257
+    rng = np.random.default_rng(5)
+    payloads = _payloads(rng, n)
+    for c in range(1, 32):
+        tags_np = rng.integers(0, c + 1, size=n).astype(np.int32)
+        tags = jnp.asarray(tags_np)
+        _assert_sort_matches(tags, c, payloads,
+                             partition_mod.partition_argsort(tags, c),
+                             f"c={c}")
+        order = np.argsort(tags_np, kind="stable")
+        got = partition_mod.sort_partition(tags, c, *payloads)
+        for g, a in zip(got, (tags_np,) + payloads):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(a)[order],
+                                          err_msg=f"c={c} vs numpy")
+        np.testing.assert_array_equal(
+            np.asarray(partition_mod.column_layout(tags, c)[1]),
+            np.bincount(tags_np, minlength=c + 1), err_msg=f"c={c} counts")
+
+
+def test_sort_under_vmap():
+    """Batched streams (``StreamSession``'s ``vmap``) sort each lane on its
+    own: lane by lane equal to the unbatched sort and layout."""
+    lanes, n, c = 3, 700, 6
+    rng = np.random.default_rng(9)
+    tags = jnp.asarray(rng.integers(0, c + 1, size=(lanes, n)), jnp.int32)
+    sym = jnp.asarray(rng.integers(0, 256, size=(lanes, n)), jnp.uint8)
+    rec = jnp.asarray(rng.integers(0, 50, size=(lanes, n)), jnp.int32)
+    got = jax.vmap(
+        lambda t, s, r: partition_mod.sort_partition(t, c, s, r))(tags, sym, rec)
+    start, count = jax.vmap(
+        lambda t: partition_mod.column_layout(t, c))(tags)
+    for lane in range(lanes):
+        want = partition_mod.partition_scatter(tags[lane], c)
+        w = partition_mod.apply_partition(want.perm, tags[lane], sym[lane],
+                                          rec[lane])
+        for g, x in zip(got, w):
+            np.testing.assert_array_equal(np.asarray(g[lane]), np.asarray(x))
+        np.testing.assert_array_equal(np.asarray(start[lane]),
+                                      np.asarray(want.col_start))
+        np.testing.assert_array_equal(np.asarray(count[lane]),
+                                      np.asarray(want.col_count))

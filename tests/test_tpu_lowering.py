@@ -14,6 +14,7 @@ this file loads the TPU compiler.  Where no topology can be described the
 fixture skips the file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,23 +121,58 @@ def test_fused_converter_lowers_batched(v5e):
     assert "conditional" in text
 
 
-def test_staged_pallas_step_lowers(v5e, monkeypatch):
+@pytest.fixture(scope="module")
+def staged_step(v5e):
     """One whole staged ``execute_plan`` step on ``backend="pallas"``,
-    planned as on a TPU: compiled kernels, the radix partition kernel."""
-    monkeypatch.setattr(backends_mod, "interpret_kernels", lambda: False)
-    cfg = ParserConfig(dfa=make_csv_dfa(), schema=Schema.of(*synth.TAXI_SCHEMA),
-                       max_records=ROWS, backend="pallas")
-    backend = backends_mod.get_backend("pallas")
-    plan = stages_mod.plan_parse(cfg, backend)
+    planned as on a TPU, compiled once for the tests below: its plan and
+    the compiled program's text."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backends_mod, "interpret_kernels", lambda: False)
+        cfg = ParserConfig(dfa=make_csv_dfa(),
+                           schema=Schema.of(*synth.TAXI_SCHEMA),
+                           max_records=ROWS, backend="pallas")
+        backend = backends_mod.get_backend("pallas")
+        plan = stages_mod.plan_parse(cfg, backend)
+        text = _compile(
+            "staged pallas step",
+            lambda ch, st: stages_mod.execute_plan(ch, plan, cfg, backend,
+                                                   initial_state=st),
+            _spec(v5e, (PARTITION // CHUNK, CHUNK), jnp.uint8),
+            _spec(v5e, (), jnp.int32))
+    return plan, text
+
+
+def test_staged_pallas_step_lowers(staged_step):
+    """The staged step compiles with its kernels, and "auto" resolves on a
+    TPU to the payload-carrying sort partition."""
+    plan, text = staged_step
     assert not plan.interpret
-    assert plan.materialize.partition_impl == "kernel"
-    text = _compile(
-        "staged pallas step",
-        lambda ch, st: stages_mod.execute_plan(ch, plan, cfg, backend,
-                                               initial_state=st),
-        _spec(v5e, (PARTITION // CHUNK, CHUNK), jnp.uint8),
-        _spec(v5e, (), jnp.int32))
+    assert plan.materialize.partition_impl == "sort"
     assert text.count('custom_call_target="tpu_custom_call"') >= 4
+
+
+def _ops_under(text, scope):
+    """``(opcode, result type)`` of every instruction of the compiled
+    program whose ``op_name`` lies under the scope ``scope``, fused
+    computations included."""
+    ops = []
+    for line in text.splitlines():
+        if f'/{scope}/' not in line or "op_name=" not in line:
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9-]*)\(", line)
+        if m:
+            ops.append((m.group(2), m.group(1)))
+    return ops
+
+
+def test_staged_step_partitions_by_sort(staged_step):
+    """Under ``stage.gather`` the compiled step sorts the partition's
+    symbols and tags into column order: one N-sized ``sort`` and no
+    ``gather`` through a permutation."""
+    _, text = staged_step
+    ops = _ops_under(text, "stage.gather")
+    assert any(op == "sort" and f"[{PARTITION}]" in ty for op, ty in ops), ops
+    assert not [op for op, _ in ops if op == "gather"], ops
 
 
 def test_megakernel_refused_on_tpu(monkeypatch):
