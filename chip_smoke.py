@@ -25,7 +25,8 @@ Phases (one chip):
   tenant's results equal its solo parse bit for bit.
 
 ``--chips 4`` runs only the paths that exist across chips:
-``DistributedParser`` on a 4-device mesh against single-device
+``DistributedParser`` on a 4-device mesh over yelp text (each chip's own
+columns, and ``assemble``'s table) against single-device
 ``Parser.to_arrow``, and a 4-lane ``StreamSession(mesh=...)`` against the
 unmeshed batched session.
 
@@ -448,6 +449,38 @@ def serve(tenant_bytes: int, partition_bytes: int, n_tenants: int = 4) -> None:
              records=records, tenants=n_tenants, **_plan_facts(solo.parser))
 
 
+def _same_shards(sharded, want: dict, cfg: ParserConfig, n_devices: int) -> None:
+    """Each chip's device columns hold its records whole: chip ``d``'s
+    records ``rec_base[d]`` on, field by field, against the single-device
+    table ``want`` (``Parser.to_arrow``)."""
+    if bool(sharded.overflow):
+        raise AssertionError("mesh: a chip's tail overflowed")
+    m, n_cols = cfg.max_records, len(cfg.schema.columns)
+    base = np.asarray(sharded.rec_base).reshape(n_devices)
+    ends = np.append(base[1:], int(sharded.n_records))
+    css = np.asarray(sharded.css).reshape(n_devices, -1)
+    off = np.asarray(sharded.field_offset).reshape(n_devices, n_cols, m)
+    ln = np.asarray(sharded.field_length).reshape(n_devices, n_cols, m)
+    for c, col in enumerate(cfg.schema.columns):
+        ref = want[col.name]
+        valid = np.unpackbits(ref["validity"], bitorder="little")
+        if col.dtype != "str":
+            value = np.asarray(sharded.values[col.name].value).reshape(n_devices, m)
+            ok = np.asarray(sharded.values[col.name].valid).reshape(n_devices, m)
+        for d in range(n_devices):
+            a, b = int(base[d]), int(ends[d])
+            if col.dtype == "str":
+                o = ref["offsets"]
+                for j in range(b - a):
+                    got = css[d, off[d, c, j]:off[d, c, j] + ln[d, c, j]]
+                    if got.tobytes() != ref["data"][o[a + j]:o[a + j + 1]].tobytes():
+                        raise AssertionError(
+                            f"mesh: chip {d} record {a + j} {col.name} differs")
+            elif (not np.array_equal(value[d, :b - a], ref["values"][a:b])
+                  or not np.array_equal(ok[d, :b - a], valid[a:b].astype(bool))):
+                raise AssertionError(f"mesh: chip {d} {col.name} differs")
+
+
 def mesh_phase(n_devices: int, table_bytes: int = 8 * MiB,
                lane_bytes: int = 4 * MiB, partition_bytes: int = MiB) -> None:
     """The paths that exist only across chips, each against its
@@ -461,10 +494,12 @@ def mesh_phase(n_devices: int, table_bytes: int = 8 * MiB,
     if len(devices) != n_devices:
         raise AssertionError(f"--chips {n_devices}: JAX sees {len(devices)}")
 
-    # DistributedParser.assemble vs single-device Parser.to_arrow
+    # DistributedParser vs single-device Parser.to_arrow, on yelp text whose
+    # quoted fields the chips' cuts land in: each chip's own columns, and
+    # assemble's table
     t0, c0 = time.perf_counter(), CLOCK.seconds
-    data = _taxi(table_bytes)
-    cfg = _cfg(synth.TAXI_SCHEMA, _max_records(len(data), 60), "pallas")
+    data = _yelp(table_bytes)
+    cfg = _cfg(synth.YELP_SCHEMA, _max_records(len(data), 100), "pallas")
     mesh = Mesh(np.array(devices), ("data",))
     dp = DistributedParser(cfg, mesh)
     chunks = jax.device_put(dp.prepare(data), NamedSharding(mesh, PartitionSpec("data")))
@@ -472,12 +507,14 @@ def mesh_phase(n_devices: int, table_bytes: int = 8 * MiB,
     if len(holders) != n_devices:
         raise AssertionError(f"mesh: input on {len(holders)} devices")
     sharded = dp.parse_chunks(chunks)
-    single = Parser(_cfg(synth.TAXI_SCHEMA, cfg.max_records, "pallas"))
-    _same_arrow(dp.assemble(sharded),
-                single.to_arrow(single.parse_chunks(single.prepare(data))),
-                "distributed vs single-device")
+    single = Parser(_cfg(synth.YELP_SCHEMA, cfg.max_records, "pallas"))
+    want = single.to_arrow(single.parse_chunks(single.prepare(data)))
+    _same_shards(sharded, want, cfg, n_devices)
+    _same_arrow(dp.assemble(sharded), want, "distributed vs single-device")
     _reading("distributed", t0, c0, bytes=len(data), devices=n_devices,
-             devices_holding_input=len(holders), **_plan_facts(single))
+             devices_holding_input=len(holders),
+             halo_bytes=np.asarray(sharded.halo_bytes).tolist(),
+             **_plan_facts(single))
 
     # 4-lane StreamSession over a mesh vs the unmeshed batched session
     t0, c0 = time.perf_counter(), CLOCK.seconds

@@ -219,9 +219,8 @@ class ParseBackend:
     #           initial_state () i32,
     #           stitch: Optional[stages.ParseStitch] = None)
     #       -> stages.ParseResult
-    # ``stitch`` carries the distributed driver's cross-shard hooks (prefix
-    # composition, offset/record-base seeding, global validation reductions)
-    # so the fused path runs per-shard under shard_map — see
+    # ``stitch`` carries the distributed driver's cross-shard validation
+    # reduction so the fused path runs per-shard under shard_map — see
     # ``stages.ParseStitch``.  None = backend has no fused path; plans
     # resolve to "staged".
     execute: Optional[Callable] = None
@@ -450,16 +449,8 @@ def _pl_execute(raw_chunks, plan, cfg, initial_state, stitch=None):
     computes it from the flat class stream.
 
     Under a ``stitch`` (distributed execution; ``stages.ParseStitch``) the
-    composite scan is seeded with the cross-device prefix, the megakernel's
-    in-kernel tagging is seeded with the shard's column offset, and
-    validation goes through the stitch's global reductions.  The column
-    seed comes from the §3.2 summaries, which the megakernel only produces
-    *internally* — so the stitched fused path runs the staged summary
-    kernel (``replay_summaries``) first for the stitch and the megakernel
-    re-replays in VMEM.  That duplicate replay is the price of keeping the
-    megakernel single-launch; the shard driver's own summary pass CSEs
-    against it, so it is one extra replay total, still O(N/D) per device
-    with O(D·|S|) collectives.
+    shard holds whole records from the record start state, so only §4.3
+    validation changes: it goes through the stitch's global reductions.
     """
     from repro.core import stages as stages_mod
     from repro.core import validation as validation_mod
@@ -470,31 +461,22 @@ def _pl_execute(raw_chunks, plan, cfg, initial_state, stitch=None):
     # composite scan — the only stages outside the megakernel.
     vecs = _pl_chunk_vectors(raw_chunks, cfg)
     scanned = tr.exclusive_scan_vectors(vecs, use_matmul=cfg.use_matmul_scan)
-    if stitch is not None:
-        prefix = stitch.prefix_fn(vecs)
-        scanned = tr.compose(jnp.broadcast_to(prefix, scanned.shape), scanned)
     start = tr.start_states(scanned, cfg.dfa, initial_state=initial_state)
-
-    col_seed = None
-    if stitch is not None:
-        _, _, _, summaries = _pl_replay_summaries(raw_chunks, start, cfg)
-        _, _, col_seed, n_total = stitch.offsets_fn(summaries)
 
     out = fused_ops.fused_parse(
         raw_chunks, start, cfg.dfa,
         tagging=mat.tagging, n_cols=mat.n_cols, max_records=mat.max_records,
         selected=mat.selected, convert=mat.convert,
         int_width=cfg.int_width, float_width=cfg.float_width,
-        col_seed=col_seed, interpret=interpret_kernels(),
+        interpret=interpret_kernels(),
     )
 
     if stitch is not None:
-        # §4.3 goes through the stitch's global reductions; the kernel's
-        # fields_per_rec is already seed-corrected at the head record.
-        val = stitch.validation_fn(
-            out.fields_per_rec, out.n_records, out.end_state,
-            out.saw_invalid, n_total,
-        )
+        # §4.3 goes through the stitch's global reductions.
+        with jax.named_scope("stage.stitch"):
+            val = stitch.validation_fn(
+                out.fields_per_rec, out.n_records, out.end_state,
+                out.saw_invalid)
         return stages_mod.ParseResult(
             css=out.css,
             col_start=out.col_start,

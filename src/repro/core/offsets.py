@@ -13,8 +13,7 @@ Two granularities are implemented:
 
   * symbol-level, via cumulative sums + a running "last record delimiter"
     cummax — the flattened equivalent used inside a single device, and
-  * chunk-level, the paper-faithful summaries consumed by the distributed
-    parser's cross-device scan.
+  * chunk-level, the paper-faithful summaries combined across chunks.
 
 Both are cross-checked in tests.
 """
@@ -124,26 +123,6 @@ def scan_chunk_offsets(summ: ChunkSummary) -> ChunkOffsets:
     col_tag = jnp.concatenate([zero + REL, t_inc[:-1]])
     col_off = jnp.concatenate([zero, o_inc[:-1]])
     return ChunkOffsets(rec_off.astype(jnp.int32), col_tag, col_off)
-
-
-def fold_summary(summ: ChunkSummary):
-    """Reduce a shard's chunk summaries to one summary triple.
-
-    Cross-device building block: the distributed parser all-gathers one
-    (rec_count, col_tag, col_off) triple per device — O(devices) bytes total,
-    independent of input size.
-    """
-    rec = summ.rec_count.sum().astype(jnp.int32)
-
-    def body(carry, x):
-        return combine_col(carry, x), None
-
-    (t, o), _ = jax.lax.scan(
-        body,
-        (jnp.int32(REL), jnp.int32(0)),
-        (summ.col_tag, summ.col_off),
-    )
-    return rec, t, o
 
 
 def symbol_ids_from_chunks(

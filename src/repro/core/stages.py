@@ -46,12 +46,13 @@ offsets make windows contiguous; ``cfg.window_rows`` /
 ``cfg.max_window_bytes``), so VMEM never holds the whole CSS and per-parse
 input size is unbounded by VMEM capacity; see ``docs/ARCHITECTURE.md``.
 
-Driver-specific glue stays in the drivers: the cross-device scans of
-``DistributedParser`` plug in via a :class:`ParseStitch` — three hooks
-(transition-composite prefix, stitched chunk offsets + shard seeds, and a
-cross-shard validation reduction) that let every shard of a mesh run this
-*same* ``execute_plan`` composition end to end (conversion included) while
-this module stays mesh-agnostic.  ``plan_parse(convert=False)`` remains
+Driver-specific glue stays in the drivers: ``DistributedParser`` finds
+each shard's context and passes every cut record's head to the shard that
+holds its end, so that each shard parses whole records from the record
+start state; its one hook, a :class:`ParseStitch`, reduces §4.3
+validation across the mesh, and every shard runs this *same*
+``execute_plan`` composition end to end (conversion included) while this
+module stays mesh-agnostic.  ``plan_parse(convert=False)`` remains
 available for index-only shard export (each host converts its own batch —
 the pre-mesh-native contract, still used by the dry-run roofline cells).
 """
@@ -193,9 +194,7 @@ class ParseResult(NamedTuple):
     field_length: jax.Array              # (n_cols, max_records) int32
     field_present: jax.Array             # (n_cols, max_records) bool — field
                                          # materialised in input (disambiguates
-                                         # empty-but-terminated from absent;
-                                         # the distributed host assembly keys
-                                         # boundary-piece recovery on it)
+                                         # empty-but-terminated from absent)
     values: Dict[str, typeconv_mod.Parsed]
     validation: validation_mod.Validation
     end_state: jax.Array                 # () int32 — carried into next partition
@@ -234,39 +233,21 @@ class ParsePlan(NamedTuple):
 
 
 class ParseStitch(NamedTuple):
-    """Cross-shard stitching hooks for running :func:`execute_plan` under
+    """The cross-shard hook for running :func:`execute_plan` under
     ``shard_map`` (the distributed driver's glue, paper Fig. 4 at mesh
     granularity).
 
-    Each hook exchanges only O(devices · |S|) summary data — never anything
-    input-sized — which is the whole scale-out argument: per-shard work is
-    N/D bytes, the stitching collectives are constant.
+    Each shard parses whole records only, from the record start state, so
+    its record and column ids are exact shard-local ids; only §4.3
+    validation needs the other shards.
 
-    ``prefix_fn(vecs (C,S)) -> (S,)``
-        Exclusive cross-device composite of the §3.1 transition summaries,
-        applied before the local exclusive scan (one all-gather of one
-        ``(S,)`` vector per device).
-    ``offsets_fn(summaries) -> (ChunkOffsets, rec_base (), col_seed (), n_total ())``
-        Globally stitched §3.2 chunk offsets plus the shard seeds: the
-        first global record id in the shard, the column offset entering the
-        shard (field delimiters since the last record delimiter before it),
-        and the global record count (one all-gather of one summary triple
-        per device).
-    ``validation_fn(fields_per_rec (M,), n_local (), end_state (), saw_invalid (), n_total ()) -> Validation``
-        Cross-shard §4.3 reduction: ``fields_per_rec`` is the shard's
-        *seed-corrected* per-record column counts on shard-local ids (the
-        boundary record's count already includes ``col_seed``), and the hook
-        reduces the global flags (accepting end state on the last shard,
-        min/max columns, conformance) across the mesh axis — O(devices)
-        scalars.  ``record_ok`` in the returned Validation stays per-shard.
-
-    With a stitch in place the executor materializes with *shard-local*
-    record ids (``record_id - rec_base``) so the field index stays small;
-    ``rec_base`` restores global ids.
+    ``validation_fn(fields_per_rec (M,), n_local (), end_state (), saw_invalid ()) -> Validation``
+        Reduces the global flags across the mesh axis (accepting end state
+        on the last shard, min/max columns, conformance, the global record
+        count): O(devices) scalars, never anything input-sized.
+        ``record_ok`` in the returned Validation stays per-shard.
     """
 
-    prefix_fn: Callable
-    offsets_fn: Callable
     validation_fn: Callable
 
 
@@ -395,7 +376,7 @@ def execute_plan(
     in its donated carry step (prepend → ``execute_plan`` → extract) and
     ``vmap``s that over a stream axis; the distributed driver runs it on
     every shard under ``shard_map`` with a :class:`ParseStitch` supplying
-    the cross-device prefixes/seeds/reductions.  ``initial_state`` overrides
+    the cross-device validation reduction.  ``initial_state`` overrides
     the DFA start state (the mid-record partition-boundary hook).
     """
     if initial_state is None:
@@ -414,53 +395,42 @@ def execute_plan(
     # Each stage runs under one ``stage.<name>`` scope, so the device ops of
     # a trace name the stage they belong to; glue between two stages goes
     # under the scope of the stage that consumes it.
-    # §3.1/§3.2 — parsing context + fused per-chunk offset summaries (the
-    # stitch plugs the cross-device composite prefix into the scan).
+    # §3.1/§3.2 — parsing context + fused per-chunk offset summaries.
     with jax.named_scope("stage.contexts"):
         ctx = determine_contexts(
-            raw_chunks, cfg, backend, initial_state=initial_state,
-            prefix_fn=None if stitch is None else stitch.prefix_fn,
-        )
+            raw_chunks, cfg, backend, initial_state=initial_state)
 
-    # §3.2 — record/column identification from the summaries.  Under a
-    # stitch the chunk offsets are globally seeded and materialization runs
-    # on shard-local record ids (rec_base restores global ids).
+    # §3.2 — record/column identification from the summaries (under a
+    # stitch the shard starts on a record, so these are shard-local ids).
     with jax.named_scope("stage.ids"):
-        if stitch is None:
-            ids = identify_symbols(ctx)
-            rec_for_index = ids.record_id
-        else:
-            offs, rec_base, col_seed, n_total = stitch.offsets_fn(ctx.summaries)
-            ids = identify_symbols(ctx, chunk_offsets=offs)
-            rec_for_index = ids.record_id - rec_base
+        ids = identify_symbols(ctx)
 
     # §3.2/§3.3 — backend-owned materialization: tagging, stable partition,
     # field index, type conversion (one shared stage, one static plan).
     cols, values = materialize(
-        raw_chunks, ctx.classes, rec_for_index, ids.column_id,
+        raw_chunks, ctx.classes, ids.record_id, ids.column_id,
         plan.materialize, cfg, backend,
     )
 
-    # §4.3 — validation (stitched: local per-record column counts, with the
-    # boundary record's count completed by the cross-device column seed,
+    # §4.3 — validation (stitched: the shard's per-record column counts,
     # reduced globally by the stitch hook).
     with jax.named_scope("stage.validate"):
         end_state = ctx.end_states[-1]
         flat_classes = ctx.classes.reshape(-1)
         if stitch is None:
             val = validation_mod.validate(
-                flat_classes, rec_for_index, end_state, ctx.saw_invalid,
+                flat_classes, ids.record_id, end_state, ctx.saw_invalid,
                 cfg.dfa, plan.materialize.max_records,
                 expected_columns=plan.expected_columns,
             )
         else:
             fpr = validation_mod.fields_per_record(
-                flat_classes, rec_for_index, plan.materialize.max_records
-            ).at[0].add(col_seed)
+                flat_classes, ids.record_id, plan.materialize.max_records)
             n_local = jnp.sum(flat_classes == RECORD_DELIM).astype(jnp.int32)
-            val = stitch.validation_fn(
-                fpr, n_local, end_state, jnp.any(ctx.saw_invalid), n_total
-            )
+    if stitch is not None:
+        with jax.named_scope("stage.stitch"):
+            val = stitch.validation_fn(fpr, n_local, end_state,
+                                       jnp.any(ctx.saw_invalid))
 
     with jax.named_scope("stage.carry"):
         end_state = end_state.astype(jnp.int32)
@@ -484,21 +454,23 @@ def determine_contexts(
     cfg,
     backend: ParseBackend,
     initial_state: Optional[jax.Array] = None,
-    prefix_fn=None,
+    vecs: Optional[jax.Array] = None,
+    prefix: Optional[jax.Array] = None,
 ) -> ParseContext:
     """§3.1: transition vectors → composite scan → replay (+§3.2 summaries).
 
-    ``prefix_fn(vecs) -> (S,)`` supplies a cross-device exclusive composite
-    (the distributed parser's all-gather stitch) applied before the local
+    ``vecs`` are the chunks' transition vectors where the caller already
+    has them; ``prefix (S,)`` is a cross-device exclusive composite (the
+    distributed parser's all-gather stitch) applied before the local
     exclusive scan; ``initial_state`` overrides the DFA start state (the
     streaming carry-over hook).
     """
     from repro.core import transition as tr
 
-    vecs = backend.chunk_vectors(chunks, cfg)
+    if vecs is None:
+        vecs = backend.chunk_vectors(chunks, cfg)
     scanned = tr.exclusive_scan_vectors(vecs, use_matmul=cfg.use_matmul_scan)
-    if prefix_fn is not None:
-        prefix = prefix_fn(vecs)
+    if prefix is not None:
         scanned = tr.compose(jnp.broadcast_to(prefix, scanned.shape), scanned)
     start = tr.start_states(scanned, cfg.dfa, initial_state=initial_state)
     classes, end_states, saw_invalid, summaries = backend.replay_summaries(
@@ -507,18 +479,10 @@ def determine_contexts(
     return ParseContext(classes, end_states, saw_invalid, summaries)
 
 
-def identify_symbols(
-    ctx: ParseContext,
-    chunk_offsets: Optional[offsets_mod.ChunkOffsets] = None,
-) -> offsets_mod.SymbolIds:
-    """§3.2: per-symbol record/column ids from the chunk summaries.
-
-    ``chunk_offsets`` overrides the local exclusive scan with externally
-    stitched offsets (the distributed parser's cross-device prefixes).
-    """
-    if chunk_offsets is None:
-        chunk_offsets = offsets_mod.scan_chunk_offsets(ctx.summaries)
-    return offsets_mod.symbol_ids_from_chunks(ctx.classes, chunk_offsets)
+def identify_symbols(ctx: ParseContext) -> offsets_mod.SymbolIds:
+    """§3.2: per-symbol record/column ids from the chunk summaries."""
+    return offsets_mod.symbol_ids_from_chunks(
+        ctx.classes, offsets_mod.scan_chunk_offsets(ctx.summaries))
 
 
 def materialize(

@@ -35,9 +35,8 @@ def fields_per_record(
     ``max_records`` are clipped into a dropped overflow segment.
 
     Shared between :func:`validate` (single device) and the distributed
-    driver, whose shards compute *local* counts on shard-local record ids
-    and stitch the boundary record's count with the cross-device column
-    seed before reducing (``core/distributed.py``).
+    driver, whose shards hold whole records and compute *local* counts on
+    shard-local record ids before reducing (``core/distributed.py``).
     """
     classes = classes.reshape(-1)
     is_fld = classes == FIELD_DELIM

@@ -93,13 +93,12 @@ def _make_pipeline_kernel(
     PAD = NB * RANK_BLOCK - N
     n_segs = n_cols * M
 
-    def kernel(chunks_ref, start_ref, seed_ref, css_ref, col_start_ref,
+    def kernel(chunks_ref, start_ref, css_ref, col_start_ref,
                col_count_ref, off_ref, len_ref, pres_ref, fpr_ref, meta_ref,
                *val_refs):
         raw_u8 = chunks_ref[...].reshape(N)               # (N,) uint8
         data = chunks_ref[...].astype(jnp.int32)          # (C, K)
         state0 = start_ref[...].astype(jnp.int32).reshape(C)
-        col_seed = seed_ref[0, 0]                         # () int32
 
         # -- 1. replay (dfa_scan one-hot select chains, classes in carry) --
         def body(k, carry):
@@ -141,10 +140,7 @@ def _make_pipeline_kernel(
             [jnp.full((1,), -1, jnp.int32), last_rec_incl[:-1]]
         )
         base = jnp.where(last_rec_excl >= 0, fld_incl[jnp.clip(last_rec_excl, 0)], 0)
-        # Until the partition's own first record delimiter, ids are offset by
-        # the cross-shard column seed (offsets.symbol_ids_from_chunks at
-        # shard granularity; 0 for single-device callers).
-        column_id = fld_excl - base + jnp.where(last_rec_excl < 0, col_seed, 0)
+        column_id = fld_excl - base
         n_records = jnp.sum(rec_i32)
 
         # -- 3. tagging (tagging.tag_symbols per mode) ---------------------
@@ -259,11 +255,8 @@ def _make_pipeline_kernel(
             val_refs[2 * i + 1][...] = ok.astype(jnp.int32)[None, :]
 
         # -- §4.3 validation inputs + §4.4 carry scalars -------------------
-        # The head record's column count includes the cross-shard seed (its
-        # leading fields live on predecessor shards; seed is 0 single-device).
         rid = jnp.where(record_id < M, record_id, M)
-        fpr = (jnp.zeros((M + 1,), jnp.int32).at[rid].add(fld_i32)[:-1] + 1
-               ).at[0].add(col_seed)
+        fpr = jnp.zeros((M + 1,), jnp.int32).at[rid].add(fld_i32)[:-1] + 1
         last_record_end = jnp.max(jnp.where(is_rec, pos, -1))
 
         css_ref[...] = css[None, :]
@@ -290,7 +283,6 @@ def pipeline_call(
     max_records: int,
     selected,
     convert,
-    col_seed=None,
     interpret: bool,
 ):
     """Run the megakernel over one partition.
@@ -300,9 +292,6 @@ def pipeline_call(
       start_states: ``(C,) int32`` per-chunk start states (from the §3.1
         composite scan — the only upstream stage; it is O(C·S), never O(N)).
       convert: tuple of ``(col_idx, dtype, width)`` for non-str columns.
-      col_seed: ``() int32`` cross-shard column offset entering this
-        partition (field delimiters since the last record delimiter before
-        it) — the distributed driver's stitch; ``None``/0 single-device.
 
     Returns ``(css (N,) u8, col_start (n_cols+1,) i32, col_count, offset
     (n_cols, M) i32, length, present (n_cols, M) bool, fields_per_rec (M,)
@@ -334,17 +323,15 @@ def pipeline_call(
             jax.ShapeDtypeStruct((1, m), vdt),             # value
             jax.ShapeDtypeStruct((1, m), jnp.int32),       # ok
         ]
-    seed = jnp.zeros((), jnp.int32) if col_seed is None else col_seed
-    seed = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     full = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(1,),
-        in_specs=[full((c, k)), full((c, 1)), full((1, 1))],
+        in_specs=[full((c, k)), full((c, 1))],
         out_specs=[full(s.shape) for s in fixed_shapes + conv_shapes],
         out_shape=fixed_shapes + conv_shapes,
         interpret=interpret,
-    )(chunks, start_states.astype(jnp.int32)[:, None], seed)
+    )(chunks, start_states.astype(jnp.int32)[:, None])
     css, col_start, col_count, off, ln, pres, fpr, meta = out[:8]
     values = tuple(
         (out[8 + 2 * i][0], out[8 + 2 * i + 1][0].astype(bool))

@@ -61,16 +61,13 @@ def fused_parse(
     convert: Tuple[Tuple[str, int, str], ...],
     int_width: int,
     float_width: int,
-    col_seed=None,
     interpret: bool,
 ) -> FusedParse:
     """One partition through the megakernel (see module docstring).
 
     ``convert`` is the plan's ``(name, col_idx, dtype)`` tuple — ``str``
     entries are served from the field index outside the kernel; the rest
-    convert in-kernel through the shared numparse cores.  ``col_seed`` is
-    the distributed stitch's cross-shard column offset (see
-    ``fused_pipeline.pipeline_call``).
+    convert in-kernel through the shared numparse cores.
     """
     kconv = tuple(
         (c, dtype, _width_for(dtype, int_width, float_width))
@@ -80,7 +77,7 @@ def fused_parse(
         fused_pipeline.pipeline_call(
             chunks, start_states, dfa, tagging=tagging, n_cols=n_cols,
             max_records=max_records, selected=selected, convert=kconv,
-            col_seed=col_seed, interpret=interpret,
+            interpret=interpret,
         )
     )
 

@@ -10,6 +10,11 @@ Coverage:
   * converted sharded parse + host ``assemble`` is bit-identical to
     ``Parser.to_arrow`` for D∈{1,2,4,8} across backends × tagging modes ×
     ``fuse_pipeline`` (the tentpole guarantee);
+  * every shard's device-resident columns, without ``assemble``, hold its
+    records whole (the cross-device carry of each cut record's head) and
+    agree with the sequential oracle: cuts inside quoted text, a record
+    across a whole shard, a cut right after a newline, and a tail past
+    ``max_carry_bytes`` that is flagged;
   * the compiled sharded executable's collective traffic is O(D·|S|) —
     byte-for-byte identical across a 4× input-size change;
   * lane-sharded ``StreamSession`` is bit-identical to the single-device
@@ -116,6 +121,110 @@ for be, fuse, tagging in COMBOS:
 print("CONVERTED_OK")
 """, n_dev)
     assert "CONVERTED_OK" in out
+
+
+_HALO = """
+import os
+sys.path.insert(0, os.path.dirname(%r))
+from oracles.csvlike import parse as oracle_parse
+
+SHARD, K, CARRY = 64, 16, 96
+
+def table(lengths, cuts_in_quotes=()):
+    # records of the given byte lengths: an int, quoted text holding a
+    # comma, a newline and a doubled quote, and a float
+    out = b""
+    for i, n in enumerate(lengths):
+        head, tail = b'%%d,"' %% i, b'",%%d.5\\n' %% i
+        fill = n - len(head) - len(tail)
+        text = (b'x,\\n""y' * n)[:fill]
+        if text.endswith(b'"') and not text.endswith(b'""'):
+            text = text[:-1] + b"z"
+        out += head + text + tail
+    assert len(out) <= 4 * SHARD, len(out)
+    return out
+
+def quoted_at(data, cut):
+    inside = False
+    for b in data[:cut]:
+        if b == 0x22:
+            inside = not inside
+    return inside
+
+CASES = {
+    # every cut inside quoted text
+    "quoted_cuts": table([40, 50, 50, 70, 36]),
+    # one record from byte 50 to 146 holds all of shard 1
+    "whole_shard": table([50, 96, 40, 60]),
+    # a newline at byte 63: shard 0 passes on an empty tail
+    "empty_tail": table([30, 34, 64, 64, 40]),
+    # a record from byte 40 to 200: shard 2 would pass on 152 bytes
+    "overflow": table([40, 160, 50]),
+}
+assert all(quoted_at(CASES["quoted_cuts"], c) for c in (64, 128, 192))
+assert CASES["empty_tail"][63:64] == b"\\n"
+
+mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
+be, fuse, tagging = COMBO
+cfg = ParserConfig(dfa=make_csv_dfa(), schema=SCHEMA, max_records=16,
+                   chunk_size=K, backend=be, tagging=tagging,
+                   fuse_pipeline=fuse)
+dp = DistributedParser(cfg, mesh, max_carry_bytes=CARRY)
+for name, data in CASES.items():
+    sh = dp.parse_chunks(dp.prepare(data, pad_to=4 * SHARD))
+    sent = np.asarray(sh.halo_bytes)
+    if name == "overflow":
+        assert bool(sh.overflow) and sent.max() > CARRY, sent
+        try:
+            dp.assemble(sh)
+        except ValueError:
+            continue
+        raise AssertionError("assemble took an overflowed parse")
+    assert not bool(sh.overflow), (name, sent)
+    if name == "empty_tail":
+        assert sent[0] == 0, sent
+    if name == "whole_shard":
+        assert sent[1] > SHARD, sent
+    want = oracle_parse(data)
+    base = np.asarray(sh.rec_base)
+    assert int(sh.n_records) == len(want), name
+    assert base[0] == 0, base
+    css = np.asarray(sh.css).reshape(4, -1)
+    off = np.asarray(sh.field_offset).reshape(4, 3, -1)
+    ln = np.asarray(sh.field_length).reshape(4, 3, -1)
+    vals = {c: [np.asarray(a).reshape(4, -1) for a in sh.values[c]]
+            for c in ("a", "b", "c")}
+    ends = list(base[1:]) + [len(want)]
+    nl = [i for i, b in enumerate(data) if b == 0x0A and not quoted_at(data, i)]
+    for d in range(4):
+        # shard d owns the records whose newline lies in its bytes
+        owned = [r for r, e in enumerate(nl) if d * SHARD <= e < (d + 1) * SHARD]
+        assert list(range(base[d], ends[d])) == owned, (name, d, owned)
+        for j, r in enumerate(owned):
+            f = want[r]
+            got = bytes(css[d, off[d, 1, j]:off[d, 1, j] + ln[d, 1, j]])
+            assert got == f[1], (name, d, j, got, f[1])
+            assert vals["a"][0][d, j] == int(f[0]) and vals["a"][1][d, j]
+            assert vals["c"][0][d, j] == np.float32(float(f[2]))
+    print("HALO_OK", name, list(sent))
+print("HALO_DONE")
+"""
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("combo", _COMBOS,
+                         ids=lambda c: "-".join(map(str, c)))
+def test_shard_columns_hold_whole_records(combo):
+    """Each shard's device-resident columns, read without ``assemble``,
+    hold exactly the records whose newline lies in its bytes, each whole,
+    field by field as the sequential oracle parses them: cuts inside
+    quoted text (a comma, a newline, a doubled quote), a record across a
+    whole shard, a cut right after a newline (an empty tail), and a tail
+    past ``max_carry_bytes``, which sets ``overflow`` and which
+    ``assemble`` refuses."""
+    out = run_with_devices(_COMMON + f"COMBO = {combo!r}\n"
+                           + _HALO % __file__, 4)
+    assert "HALO_DONE" in out, out
 
 
 @pytest.mark.slow

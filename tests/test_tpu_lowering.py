@@ -18,8 +18,9 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core import ParserConfig, Schema, make_csv_dfa
 from repro.core import backends as backends_mod
@@ -29,10 +30,13 @@ from repro.data import synth
 CHUNK = 64                  # bytes per chunk, the default chunk_size
 PARTITION = 4 << 20         # bytes per parse partition
 ROWS = 1 << 16              # field rows per converted column
+SHARD = 32 << 20            # bytes per chip of a four-chip one-shot load
+HALO = 64 << 10             # the longest tail a chip passes on
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def v5e_2x2():
+    """The four chips of a described v5e host."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
         from jax.experimental import topologies
@@ -42,7 +46,12 @@ def v5e():
                                                 topology_name="v5e:2x2")
         except Exception as e:  # no TPU compiler in this installation
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -175,6 +184,15 @@ def test_staged_step_partitions_by_sort(staged_step):
     assert not [op for op, _ in ops if op == "gather"], ops
 
 
+def test_staged_step_has_no_mesh_stages(staged_step):
+    """A one-chip step holds no cross-chip work: no op under the mesh
+    driver's ``stage.stitch`` or ``stage.halo`` scopes, and no collective."""
+    _, text = staged_step
+    assert not _ops_under(text, "stage.stitch")
+    assert not _ops_under(text, "stage.halo")
+    assert not _COLLECTIVE.search(text)
+
+
 def test_megakernel_refused_on_tpu(monkeypatch):
     """``fuse_pipeline=True`` names the missing lowering on a TPU instead of
     resolving to the staged path or the interpreter."""
@@ -187,3 +205,60 @@ def test_megakernel_refused_on_tpu(monkeypatch):
                            fuse_pipeline=True)
     plan = stages_mod.plan_parse(ref_cfg, backends_mod.get_backend("reference"))
     assert plan.execute_path == "staged"
+
+
+@pytest.fixture(scope="module")
+def mesh_step(v5e_2x2):
+    """The ``DistributedParser`` step of a yelp table over the four chips,
+    32 MiB a chip with a 64 KiB halo, compiled once: its memory analysis
+    and the compiled program's text."""
+    from repro.core.distributed import DistributedParser
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backends_mod, "interpret_kernels", lambda: False)
+        cfg = ParserConfig(dfa=make_csv_dfa(),
+                           schema=Schema.of(*synth.YELP_SCHEMA),
+                           max_records=1 << 19, backend="pallas",
+                           validate_columns=True)
+        dp = DistributedParser(cfg, Mesh(np.array(v5e_2x2), ("data",)),
+                               max_carry_bytes=HALO)
+        compiled = dp.lower(4 * SHARD // CHUNK, CHUNK).compile()
+    return compiled.memory_analysis(), compiled.as_text()
+
+
+def test_mesh_step_fits_a_chip(mesh_step):
+    """Each chip's working set of the four-chip step fits a v5e's 16 GB."""
+    ma, _ = mesh_step
+    per_device = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                  + ma.output_size_in_bytes)
+    print(f"mesh step: temp_bytes={ma.temp_size_in_bytes} "
+          f"per_device={per_device}")
+    assert per_device < 16e9
+
+
+_COLLECTIVE = re.compile(
+    r"= (.*?) (all-gather|all-reduce|collective-permute|all-to-all|"
+    r"reduce-scatter|collective-broadcast)(-start)?\(")
+_BYTES = {"u8": 1, "pred": 1, "s32": 4, "u32": 4, "f32": 4}
+
+
+def test_mesh_step_moves_no_input_sized_data(mesh_step):
+    """The four-chip step's only collectives are summary-sized all-gathers
+    and all-reduces, and one collective-permute: the fixed halo buffer and
+    its length."""
+    _, text = mesh_step
+    found = []
+    for line in text.splitlines():
+        m = _COLLECTIVE.search(line)
+        if m:
+            # the largest array of its result (an async start's result
+            # holds its operand beside it)
+            size = max(_BYTES[dt] * int(np.prod([int(x) for x in dims.split(",")
+                                                 if x]))
+                       for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                                  m.group(1)))
+            found.append((m.group(2), size))
+    kinds = {kind for kind, _ in found}
+    assert kinds <= {"all-gather", "all-reduce", "collective-permute"}, found
+    assert [s for k, s in found if k == "collective-permute"] == [HALO + 4], found
+    assert all(s <= 4096 for k, s in found if k != "collective-permute"), found
